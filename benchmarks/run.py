@@ -117,6 +117,9 @@ def _dump(path: str, records) -> None:
 
 
 def main() -> None:
+    from repro.ioutil import enable_compile_cache
+    enable_compile_cache(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))))
     quick = "--quick" in sys.argv
     from benchmarks import (bench_faults, bench_imgproc, bench_kernels,
                             bench_mac, bench_serve, fig5_image,

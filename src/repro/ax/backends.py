@@ -19,9 +19,9 @@ Orthogonal to the backend, every add-shaped primitive takes an execution
                    without one fall back to the reference form).
 - ``"lut"``        the compiled ``2^m x 2^m`` low-part table
                    (:mod:`repro.ax.lut`): one gather + one exact high
-                   add.  numpy and jax backends; the Pallas backends
-                   support it for the elementwise ``add`` only
-                   (``repro.kernels.lut_add``).
+                   add.  numpy and jax backends only: a VMEM table
+                   gather has no Mosaic lowering, so the Pallas
+                   backends refuse it.
 
 All strategies and backends are bit-identical for the ops they share —
 enforced by the cross-strategy/cross-backend sweeps in
@@ -142,12 +142,10 @@ class Backend:
         return True
 
     def preferred_strategy(self, spec: AdderSpec) -> str:
-        """The fastest known concrete strategy for this backend — what
-        ``strategy="auto"`` resolves to.  The measured default
-        (BENCH_kernels.json): the algebraically-fused forms win on the
-        XLA/Pallas vector backends, while the table gather wins on the
-        host (but LOSES ~3x on jax — the foot-gun "auto" exists to
-        avoid)."""
+        """The concrete strategy ``strategy="auto"`` resolves to: the
+        algebraically-fused form on the XLA/Pallas vector backends (the
+        host backend overrides it).  The choice rests on CPU timings
+        (BENCH_kernels.json); no chip measurement backs it yet."""
         return "fused"
 
     def add(self, a, b, spec: AdderSpec, *, strategy: str = "reference"):
@@ -256,10 +254,11 @@ def _norm_weights(weights, k: int):
 def edge_taps(xp, q, axis: int, offsets):
     """Replicate-padded shifted views of a filter tap, as a list: the
     j-th view satisfies ``out[j][..., i] = q[..., i + offsets[j]]``
-    along ``axis`` with edges replicated.  THE tap builder — the
-    backend filter chains and the Pallas conv-chain kernel body both
-    consume it, so edge handling lives in exactly one place.  Works for
-    numpy and jax arrays (``xp`` is the array module)."""
+    along ``axis`` with edges replicated.  THE tap builder of the
+    numpy/jax filter chains, and the oracle of the Pallas kernels'
+    rotation-built views (:mod:`repro.kernels.stencil`; Mosaic has no
+    edge pad).  Works for numpy and jax arrays (``xp`` is the array
+    module)."""
     axis = axis % q.ndim
     left = max(-min(offsets), 0)
     right = max(max(offsets), 0)
@@ -280,9 +279,9 @@ def conv_taps(xp, q, kh: int, kw: int):
     """Replicate-padded shifted views for a (kh, kw) 2D kernel over the
     trailing (H, W) dims, row-major tap order: view (dy, dx) at output
     (y, x) reads ``q[y + dy - kh//2, x + dx - kw//2]`` (edges
-    replicated).  THE 2D tap builder — the backend conv datapaths and
-    the Pallas MAC kernel body all consume it, like :func:`edge_taps`
-    for the separable chains."""
+    replicated).  THE 2D tap builder of the numpy/jax conv datapaths,
+    and the oracle of the Pallas MAC kernel's views, like
+    :func:`edge_taps` for the separable chains."""
     cy, cx = kh // 2, kw // 2
     pad = [(0, 0)] * (q.ndim - 2) + [(cy, kh - 1 - cy),
                                      (cx, kw - 1 - cx)]
@@ -458,11 +457,12 @@ def _like(x, ref_dtype):
     return x.astype(ref_dtype)
 
 
-def lut_gather_add_u32(a, b, table, spec: AdderSpec):
-    """THE LUT add on uint32 lanes: one table gather + one exact high
-    add, mod 2^N.  ``table`` is the packed uint16 array — a jit
-    constant here, a VMEM ref block inside the Pallas kernel
-    (``repro.kernels.lut_add``); both consume this one formula."""
+def lut_add_mod_u32(a, b, spec: AdderSpec):
+    """THE LUT add on uint32 lanes (jax): one table gather + one exact
+    high add, mod 2^N.  The packed uint16 table is a compile-time
+    constant of the (spec,)-keyed jit cache, shared with the host
+    path's numpy table."""
+    table = jnp.asarray(lut_lib.compile_lut(spec))
     m = spec.lsm_bits
     low = jnp.uint32((1 << m) - 1)
     entry = jnp.take(table, (a & low) << m | (b & low)).astype(jnp.uint32)
@@ -472,38 +472,23 @@ def lut_gather_add_u32(a, b, table, spec: AdderSpec):
     return s
 
 
-def lut_add_mod_u32(a, b, spec: AdderSpec):
-    """LUT-strategy add mod 2^N on uint32 lanes (jax).  The table is a
-    compile-time constant of the (spec,)-keyed jit cache, shared with
-    the host path's numpy table."""
-    return lut_gather_add_u32(a, b, jnp.asarray(lut_lib.compile_lut(spec)),
-                              spec)
-
-
 def _add_mod_u32(a, b, spec: AdderSpec, strategy: str):
-    """Strategy dispatch on uint32 container lanes (shared by the jitted
-    jax entry points and the Pallas kernel bodies)."""
+    """Strategy dispatch on uint32 container lanes (the jitted jax
+    entry points)."""
     if _use_lut(spec, strategy):
         return lut_add_mod_u32(a, b, spec)
     return approx_add_mod(a, b, spec, fast=_fast(strategy))
 
 
-def mul_lut_gather_u32(a, b, table, mul_spec: MulSpec):
-    """THE LUT multiply on uint32 lanes: one full-product table gather.
-    ``table`` is a jit constant here and a VMEM ref block inside the
-    Pallas kernel (``repro.kernels.mac``); both consume this formula."""
-    n = mul_spec.n_bits
-    mask = jnp.uint32((1 << n) - 1)
-    idx = ((a & mask) << n) | (b & mask)
-    return jnp.take(table, idx).astype(jnp.uint32)
-
-
 def _mul_u32(a, b, mul_spec: MulSpec, strategy: str):
-    """Multiplier strategy dispatch on uint32 container lanes."""
+    """Multiplier strategy dispatch on uint32 container lanes; the lut
+    strategy is one full-product table gather from a jit constant."""
     if _use_mul_lut(mul_spec, strategy):
-        return mul_lut_gather_u32(
-            a, b, jnp.asarray(mul_lut_lib.compile_mul_lut(mul_spec)),
-            mul_spec)
+        table = jnp.asarray(mul_lut_lib.compile_mul_lut(mul_spec))
+        n = mul_spec.n_bits
+        mask = jnp.uint32((1 << n) - 1)
+        return jnp.take(table, ((a & mask) << n) | (b & mask)) \
+            .astype(jnp.uint32)
     return approx_mul(a, b, mul_spec, fast=_fast(strategy))
 
 
@@ -724,19 +709,14 @@ def _pallas_elementwise_add(a, b, spec: AdderSpec, interpret: bool,
     """Tile plumbing for the fused elementwise kernel: flatten to a
     (rows, 256) grid with ONE pad per operand (no intermediate zeros
     buffer), run the kernel, slice back.  The strategy reaches the
-    kernel body: reference/fused select the registered impl, lut runs
-    the VMEM-table gather kernel (``repro.kernels.lut_add``)."""
+    kernel body, which runs the registered reference or fused impl."""
+    from repro.kernels.approx_add import approx_add_pallas
     shape = a.shape
     size = int(np.prod(shape)) if shape else 1
     ap = _as_tiles(a.reshape(-1), size)
     bp = _as_tiles(b.reshape(-1), size)
-    if _use_lut(spec, strategy):
-        from repro.kernels.lut_add import lut_add_pallas
-        out = lut_add_pallas(ap, bp, spec, interpret=interpret)
-    else:
-        from repro.kernels.approx_add import approx_add_pallas
-        out = approx_add_pallas(ap, bp, spec, interpret=interpret,
-                                fast=_fast(strategy))
+    out = approx_add_pallas(ap, bp, spec, interpret=interpret,
+                            fast=_fast(strategy))
     return out.reshape(-1)[:size].reshape(shape)
 
 
@@ -783,7 +763,7 @@ def _pallas_elementwise_mul(a, b, mul_spec: MulSpec, interpret: bool,
     ap = _as_tiles(a.reshape(-1), size)
     bp = _as_tiles(b.reshape(-1), size)
     out = mul_elementwise_pallas(ap, bp, mul_spec, interpret=interpret,
-                                 strategy=strategy)
+                                 fast=_fast(strategy))
     return out.reshape(-1)[:size].reshape(shape)
 
 
@@ -793,9 +773,9 @@ def _pallas_elementwise_mul(a, b, mul_spec: MulSpec, interpret: bool,
 def _pallas_mac_matmul(a, b, spec: AdderSpec, mul_spec: MulSpec, block,
                        interpret: bool, fast: bool):
     """Pad/slice plumbing for the MAC GEMM kernel.  Zero padding is
-    harmless in every dimension: padded operands gather table entry 0
-    (= 0) so in-tile partials are unchanged, and padded M/N lanes are
-    sliced away."""
+    harmless in every dimension: a zero operand's product is 0, so
+    in-tile partials are unchanged, and padded M/N lanes are sliced
+    away."""
     from repro.kernels.mac import mac_matmul_pallas
     bm, bn, bk = block
     ap, m0, _ = _pad2(a.astype(jnp.int32), bm, bk)
@@ -813,8 +793,9 @@ class PallasBackend(Backend):
     interpret = True
 
     def _kernel_strategy(self, spec, strategy, what):
-        """The Pallas accumulation kernels fold the registered impls in
-        VMEM; the lut strategy only exists for the elementwise add."""
+        """The Pallas kernels evaluate the registered impls in VMEM; a
+        table gather from VMEM has no Mosaic lowering, so there is no
+        lut strategy on these backends."""
         if _use_lut(spec, strategy):
             raise NotImplementedError(
                 f"the lut strategy is not implemented for {what} on the "
@@ -823,6 +804,7 @@ class PallasBackend(Backend):
         return strategy
 
     def add(self, a, b, spec, *, strategy="reference"):
+        self._kernel_strategy(spec, strategy, "add")
         return _pallas_elementwise_add(jnp.asarray(a), jnp.asarray(b), spec,
                                        self.interpret, strategy)
 
@@ -841,12 +823,11 @@ class PallasBackend(Backend):
                                    fast=_fast(strategy))
 
     def mul(self, a, b, mul_spec, *, strategy="reference"):
-        if _use_mul_lut(mul_spec, strategy) \
-                and not mul_lut_lib.mul_lut_supported(mul_spec):
+        if _use_mul_lut(mul_spec, strategy):
             raise NotImplementedError(
-                f"no compilable product table for {mul_spec.short_name} "
-                f"(n_bits > {mul_lut_lib.MAX_MUL_LUT_BITS}); use "
-                f"strategy='fused'")
+                f"the lut strategy (a product table gather) is not "
+                f"implemented for mul on the {self.name!r} backend; use "
+                f"strategy='fused' (or the numpy/jax backends for lut)")
         return _pallas_elementwise_mul(jnp.asarray(a), jnp.asarray(b),
                                        mul_spec, self.interpret,
                                        _require_concrete(strategy))
@@ -888,10 +869,7 @@ class PallasTpuBackend(PallasBackend):
     interpret = False
 
     def available(self) -> bool:
-        try:
-            return jax.default_backend() == "tpu"
-        except Exception:  # pragma: no cover - backend probe
-            return False
+        return jax.default_backend() == "tpu"
 
 
 # --------------------------------------------------------------- registry --

@@ -6,7 +6,7 @@ vertical break and Mitchell's interpolation touch every bit), so unlike
 the adder LUTs these tables cover the full ``2^N x 2^N`` operand domain
 — which is why compilation is capped at :data:`MAX_MUL_LUT_BITS`
 operand bits (a 10-bit signed MAC table is 4 MiB of int32; an 8-bit one
-is 128 KiB of uint16, VMEM-resident on TPU).
+is 128 KiB of uint16).
 
 Tables are process-cached per *canonical* spec (irrelevant knobs zeroed
 via ``effective_*``) and returned read-only, exactly like the adder
@@ -19,9 +19,11 @@ Three table families:
 * :func:`mul_error_delta_table` — signed ``approx - exact`` deltas over
   the same domain; the raw material for the exact analytics.
 * :func:`signed_mul_table` / :func:`tap_tables` — signed
-  (sign-magnitude) product tables for the MAC datapaths: matmul gathers
-  the 2D table per (a, b) lane pair; conv2d gathers one 1D per-tap
-  column table per static kernel weight.
+  (sign-magnitude) product tables for the numpy/jax MAC datapaths:
+  matmul gathers the 2D table per (a, b) lane pair; conv2d gathers one
+  1D per-tap column table per static kernel weight.  The Pallas MAC
+  kernels compute the same sign-magnitude products in the kernel body
+  (Mosaic lowers only 2-D gathers).
 """
 
 from __future__ import annotations
@@ -232,9 +234,9 @@ def tap_tables(spec: MulSpec, weights: Tuple[int, ...]) -> np.ndarray:
     sign(w_t) * approx(v, |w_t|)`` for input magnitudes ``v``, shaped
     ``(len(weights), 2^N)`` int32.
 
-    One gather per tap replaces the multiplier entirely at runtime —
-    the conv datapaths on every backend share these exact tables, which
-    is what makes them bit-identical by construction.
+    One gather per tap replaces the multiplier entirely at runtime on
+    the numpy/jax conv datapaths; the Pallas kernel evaluates the same
+    entries in-kernel, held to these tables by the cross-backend tests.
     """
     return _tap_tables_cached(_canonical(spec), tuple(int(w)
                                                       for w in weights))

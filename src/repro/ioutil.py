@@ -1,4 +1,5 @@
-"""Shared durable-I/O primitives: SHA-256 digests + atomic publishes.
+"""Shared durable-I/O primitives: SHA-256 digests + atomic publishes,
+and the placement of JAX's persistent compilation cache.
 
 Two subsystems persist binary artifacts with integrity manifests — the
 training checkpointer (:mod:`repro.checkpoint.checkpointer`) and the
@@ -55,3 +56,26 @@ def atomic_replace_dir(tmp: str, final: str) -> None:
     if os.path.exists(final):
         shutil.rmtree(final)
     os.rename(tmp, final)
+
+
+#: JAX's own variable naming its persistent compilation cache directory.
+COMPILE_CACHE_ENV = "JAX_COMPILATION_CACHE_DIR"
+
+
+def enable_compile_cache(root: str) -> str:
+    """Turn on JAX's persistent compilation cache for an entry point
+    (a script's ``main``; never on import) and return its directory.
+
+    Where :data:`COMPILE_CACHE_ENV` is set, JAX already reads it and no
+    other directory is set.  Otherwise the cache goes to the fixed
+    ``<root>/.jax_cache``, so a later run from the same checkout finds
+    it.  A Pallas kernel compiles in a second or two, under JAX's
+    default one-second floor for keeping an entry, so the floor is
+    lowered to zero."""
+    import jax
+    directory = os.environ.get(COMPILE_CACHE_ENV)
+    if not directory:
+        directory = os.path.join(os.path.abspath(root), ".jax_cache")
+        jax.config.update("jax_compilation_cache_dir", directory)
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+    return directory

@@ -49,7 +49,7 @@ def _kernel(t_ref, o_ref, *, spec: AdderSpec, weights, fast: bool):
 
 
 def accumulate_pallas(terms, spec: AdderSpec, *, weights=None,
-                      block=(256, 256), interpret: bool = True,
+                      block=(256, 256), interpret: bool,
                       fast: bool = False):
     """terms: int32 (K, M, N) two's-complement containers; returns the
     weighted approximate fold, int32 (M, N).  ``weights`` are K static

@@ -33,7 +33,7 @@ def _kernel(a_ref, b_ref, o_ref, *, spec: AdderSpec, fast: bool):
 
 
 def approx_add_pallas(a, b, spec: AdderSpec, *, block=(256, 256),
-                      interpret: bool = True, fast: bool = False):
+                      interpret: bool, fast: bool = False):
     """a, b: int32 (M, N) two's-complement fixed point; returns int32.
 
     ``fast`` selects the registered algebraically-fused adder form for

@@ -41,7 +41,7 @@ def _kernel(a_ref, b_ref, o_ref, *, spec: AdderSpec, fast: bool):
 
 
 def approx_matmul_pallas(a, b, spec: AdderSpec, *,
-                         block=(128, 128, 128), interpret: bool = True,
+                         block=(128, 128, 128), interpret: bool,
                          fast: bool = False):
     """a: int8 (M, K); b: int8 (K, N) -> int32 (M, N).
 

@@ -85,7 +85,7 @@ def _kernel(ar_ref, ai_ref, br_ref, bi_ref, wr_ref, wi_ref,
 
 def butterfly_pallas(a_re, a_im, b_re, b_im, w_re, w_im,
                      spec: AdderSpec, *, inverse: bool = False,
-                     block_rows: int = 256, interpret: bool = True):
+                     block_rows: int = 256, interpret: bool):
     """All inputs int32 (rows, half); twiddles int32 (half,) Q1.14.
     Returns (top_re, top_im, bot_re, bot_im)."""
     rows, half = a_re.shape

@@ -1,20 +1,20 @@
-"""Pallas kernel: multi-stage separable-filter chain on one VMEM tile.
+"""Pallas kernel: multi-stage separable-filter chain on VMEM-resident rows.
 
 A pipeline of separable filter passes (the gaussian/box blurs, the
 sobel smooth+diff gradients) dispatched pass-by-pass costs one HBM
 round-trip of the full image per pass: write the stage output, read it
-back as the next stage's input.  This kernel keeps the image tile
-resident in VMEM across ALL stages: the tile is read once, every
-:class:`~repro.ax.backends.FilterStage` — replicate-padded taps, exact
-integer tap weights, the K-1 approximate adds, sign extension and the
-exact rounding shift — runs on the resident registers/VMEM values, and
-the final stage's output is written once.
+back as the next stage's input.  This kernel keeps each block of rows
+resident in VMEM across ALL stages: the block (plus its halo) is read
+once, every :class:`~repro.ax.backends.FilterStage` — replicate-edge
+taps, exact integer tap weights, the K-1 approximate adds, sign
+extension and the exact rounding shift — runs on the resident values,
+and the final stage's output is written once.
 
-The grid runs one program per leading-batch image with the full (H, W)
-plane as the block: a 512x512 int32 plane is 1 MiB resident (plus the
-pad halo), well inside a TPU core's ~16 MiB VMEM.  The per-stage math
-is the exact sequence the jax backend emulation performs, so the chain
-is bit-identical to stage-by-stage ``accumulate_signed`` dispatches.
+The grid runs one program per (image, row block); the halo is the sum
+of the vertical stages' tap reach (:mod:`repro.kernels.stencil`).  The
+per-stage math is the exact sequence the jax backend emulation
+performs, so the chain is bit-identical to stage-by-stage
+``accumulate_signed`` dispatches.
 """
 
 from __future__ import annotations
@@ -24,24 +24,25 @@ import functools
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental import pallas as pl
 
-from repro.ax.backends import edge_taps
 from repro.core.adders import approx_add_mod
 from repro.core.specs import AdderSpec
 from repro.kernels.accumulate import scale_mod_u32
+from repro.kernels.stencil import edge_views, plane_index, row_blocked_call
 
 
-def _kernel(q_ref, o_ref, *, spec: AdderSpec, stages, fast: bool):
-    x = q_ref[0]
+def _chain(x, row0, *, spec: AdderSpec, stages, fast: bool, h: int,
+           w: int):
+    rows, cols = plane_index(x.shape, row0)
     mask = jnp.int32((1 << spec.n_bits) - 1)
     sign = jnp.int32(1 << (spec.n_bits - 1))
     for st in stages:
+        axis, index, n = (0, rows, h) if st.axis == -2 else (1, cols, w)
         acc = None
-        for view, w in zip(edge_taps(jnp, x, st.axis, st.offsets),
-                           st.weights):
+        for view, wt in zip(edge_views(x, axis, st.offsets, index, n),
+                            st.weights):
             u = jax.lax.bitcast_convert_type(view & mask, jnp.uint32)
-            u = scale_mod_u32(u, w, spec.n_bits)
+            u = scale_mod_u32(u, wt, spec.n_bits)
             acc = u if acc is None else approx_add_mod(acc, u, spec,
                                                        fast=fast)
         s = jax.lax.bitcast_convert_type(acc, jnp.int32)
@@ -49,13 +50,13 @@ def _kernel(q_ref, o_ref, *, spec: AdderSpec, stages, fast: bool):
         if st.shift:
             s = (s + (1 << (st.shift - 1))) >> st.shift
         x = s
-    o_ref[0] = x
+    return x
 
 
 @functools.partial(jax.jit,
                    static_argnames=("spec", "stages", "interpret", "fast"))
-def filter_chain_pallas(q, spec: AdderSpec, stages, *,
-                        interpret: bool = True, fast: bool = False):
+def filter_chain_pallas(q, spec: AdderSpec, stages, *, interpret: bool,
+                        fast: bool = False):
     """q: signed int32 (..., H, W) fixed-point containers of
     ``spec.n_bits`` significant bits; ``stages`` a static tuple of
     :class:`~repro.ax.backends.FilterStage` with axes -1/-2.  Returns
@@ -73,17 +74,13 @@ def filter_chain_pallas(q, spec: AdderSpec, stages, *,
             raise ValueError(f"{len(st.weights)} weights for "
                              f"{len(st.offsets)} taps")
         norm.append(st._replace(axis=ax))
-    stages = tuple(norm)
     shape = q.shape
     h, w = shape[-2:]
     b = int(np.prod(shape[:-2])) if shape[:-2] else 1
-    out = pl.pallas_call(
-        functools.partial(_kernel, spec=spec, stages=tuple(stages),
-                          fast=fast),
-        out_shape=jax.ShapeDtypeStruct((b, h, w), jnp.int32),
-        grid=(b,),
-        in_specs=[pl.BlockSpec((1, h, w), lambda i: (i, 0, 0))],
-        out_specs=pl.BlockSpec((1, h, w), lambda i: (i, 0, 0)),
-        interpret=interpret,
-    )(q.reshape(b, h, w))
+    reach = sum(max(max(st.offsets), -min(st.offsets), 0)
+                for st in norm if st.axis == -2)
+    body = functools.partial(_chain, spec=spec, stages=tuple(norm),
+                             fast=fast, h=h, w=w)
+    out = row_blocked_call(body, q.reshape(b, h, w), reach=reach,
+                           interpret=interpret, name="conv_chain")
     return out.reshape(shape)

@@ -1,34 +1,34 @@
 """Pallas MAC kernels: approximate products composed with approximate
 accumulation, VMEM-resident.
 
-Three entry points, mirroring the adder-side kernel set:
+Three entry points, mirroring the adder-side kernel set.  Every product
+is computed in the kernel body by the registered multiplier impl
+(:func:`repro.ax.mul.impls.approx_mul`): Mosaic lowers only 2-D
+gathers, so no product table rides along as a VMEM operand.
 
 * :func:`mul_elementwise_pallas` — the elementwise approximate
-  multiplier on (256, 256) int32 tiles; reference/fused run the
-  registered impl in-kernel, ``lut`` gathers the full-product table
-  riding along as a grid-invariant VMEM operand (an 8-bit table is
-  128 KiB of uint16 — cheaper than the 15+ vector ops of the array
-  emulation).
+  multiplier on (256, 256) int32 tiles.
 
 * :func:`mac_matmul_pallas` — signed MAC GEMM.  Where the exact-product
   kernel (``approx_matmul.py``) feeds the MXU, an approximate-multiplier
-  MAC array has nothing to ship to the MXU: every product is a gather
-  from the signed sign-magnitude product table (``repro.ax.mul.lut``),
-  accumulated EXACTLY within the K tile (int32 wraparound is associative
-  mod 2^32, so in-tile order cannot matter), with the approximate adder
-  on the inter-tile accumulator — the same placement as the adder-only
-  kernel.  Grid (M/bm, N/bn, K/bk), K innermost, output block revisited.
+  MAC array has nothing to ship to the MXU: each K step multiplies one
+  column of the A tile (rotated into lane 0) by one row of the B tile
+  in sign-magnitude form, and the products accumulate EXACTLY within
+  the K tile (int32 wraparound is associative mod 2^32, so in-tile
+  order cannot matter), with the approximate adder on the inter-tile
+  accumulator — the same placement as the adder-only kernel.  Grid
+  (M/bm, N/bn, K/bk), K innermost, output block revisited.
 
 * :func:`conv2d_mac_pallas` — the 2D MAC convolution: per-tap
-  sign-magnitude product columns (one 2^w-entry int32 table per static
-  kernel weight) resident in VMEM, gathered per pixel, folded through
-  the approximate adder, sign-extended, exact rounding shift.  One
-  program per batch image with the full (H, W) plane as the block,
-  exactly like the filter-chain kernel.
+  sign-magnitude products against the static kernel weights, folded
+  through the approximate adder, sign-extended, exact rounding shift.
+  One program per (image, row block) with an in-kernel halo, exactly
+  like the filter-chain kernel (:mod:`repro.kernels.stencil`).
 
 All three are bit-identical to the jax/numpy MAC paths by construction:
-products come from the same compiled tables (or the same portable
-impls), and the fold order is the same.
+the sign-magnitude rule and the operand order are those of the product
+tables the other backends gather from (``repro.ax.mul.lut``), and the
+fold order is the same.
 """
 
 from __future__ import annotations
@@ -39,12 +39,13 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from repro.ax.mul.impls import approx_mul
-from repro.ax.mul.lut import compile_mul_lut, signed_mul_table, tap_tables
 from repro.ax.mul.specs import MulSpec
 from repro.core.adders import approx_add_mod
 from repro.core.specs import AdderSpec
+from repro.kernels.stencil import edge_views, plane_index, row_blocked_call
 
 
 # ------------------------------------------------- elementwise mul --
@@ -56,43 +57,19 @@ def _mul_kernel(a_ref, b_ref, o_ref, *, mul_spec: MulSpec, fast: bool):
     o_ref[...] = jax.lax.bitcast_convert_type(p, jnp.int32)
 
 
-def _mul_lut_kernel(a_ref, b_ref, t_ref, o_ref, *, mul_spec: MulSpec):
-    from repro.ax.backends import mul_lut_gather_u32
-    au = jax.lax.bitcast_convert_type(a_ref[...], jnp.uint32)
-    bu = jax.lax.bitcast_convert_type(b_ref[...], jnp.uint32)
-    p = mul_lut_gather_u32(au, bu, t_ref[...], mul_spec)
-    o_ref[...] = jax.lax.bitcast_convert_type(p, jnp.int32)
-
-
-def mul_elementwise_pallas(a, b, mul_spec: MulSpec, *, block=(256, 256),
-                           interpret: bool = True,
-                           strategy: str = "reference"):
+def mul_elementwise_pallas(a, b, mul_spec: MulSpec, *, interpret: bool,
+                           block=(256, 256), fast: bool = False):
     """a, b: int32 (M, N) unsigned N-bit container patterns; returns the
     full approximate product, int32 (M, N)."""
     assert a.shape == b.shape and a.ndim == 2
     m, n = a.shape
     bm, bn = min(block[0], m), min(block[1], n)
     assert m % bm == 0 and n % bn == 0, "pad to block multiples"
-    grid = (m // bm, n // bn)
-    out_shape = jax.ShapeDtypeStruct((m, n), jnp.int32)
     tile = pl.BlockSpec((bm, bn), lambda i, j: (i, j))
-    if strategy == "lut" and not mul_spec.is_exact:
-        table = jnp.asarray(compile_mul_lut(mul_spec))
-        entries = int(np.prod(table.shape))
-        return pl.pallas_call(
-            functools.partial(_mul_lut_kernel, mul_spec=mul_spec),
-            out_shape=out_shape,
-            grid=grid,
-            in_specs=[tile, tile,
-                      pl.BlockSpec((entries,), lambda i, j: (0,))],
-            out_specs=tile,
-            interpret=interpret,
-        )(a, b, table)
     return pl.pallas_call(
-        functools.partial(_mul_kernel, mul_spec=mul_spec,
-                          fast=(strategy == "fused")),
-        out_shape=out_shape,
-        grid=grid,
+        functools.partial(_mul_kernel, mul_spec=mul_spec, fast=fast),
+        out_shape=jax.ShapeDtypeStruct((m, n), jnp.int32),
+        grid=(m // bm, n // bn),
         in_specs=[tile, tile],
         out_specs=tile,
         interpret=interpret,
@@ -101,23 +78,33 @@ def mul_elementwise_pallas(a, b, mul_spec: MulSpec, *, block=(256, 256),
 
 # --------------------------------------------------- MAC matmul --
 
-def _mac_matmul_kernel(a_ref, b_ref, t_ref, o_ref, *, spec: AdderSpec,
+def _signed_operand(v, w: int):
+    """The N-bit two's-complement value of int32 lanes ``v`` in
+    sign-magnitude form: (magnitude as uint32, sign in {-1, 0, 1}) —
+    the rule ``repro.ax.mul.lut.signed_mul_table`` is built by."""
+    half = 1 << (w - 1)
+    s = ((v & ((1 << w) - 1)) ^ half) - half
+    mag = jax.lax.bitcast_convert_type(jnp.abs(s), jnp.uint32)
+    return mag, (s > 0).astype(jnp.int32) - (s < 0).astype(jnp.int32)
+
+
+def _mac_matmul_kernel(a_ref, b_ref, o_ref, *, spec: AdderSpec,
                        mul_spec: MulSpec, fast: bool, bk: int):
-    av = a_ref[...]                        # (bm, bk) int32 lanes
-    bv = b_ref[...]                        # (bk, bn) int32 lanes
-    table = t_ref[...]                     # (4^w,) int32
+    bm, bn = o_ref.shape
     w = mul_spec.n_bits
-    maskw = jnp.int32((1 << w) - 1)
-    bm, bn = av.shape[0], bv.shape[1]
 
-    def body(j, acc):
-        col = jax.lax.dynamic_slice(av, (0, j), (bm, 1))
-        row = jax.lax.dynamic_slice(bv, (j, 0), (1, bn))
-        idx = ((col & maskw) << w) | (row & maskw)
-        return acc + jnp.take(table, idx)
+    def step(j, carry):
+        a, part = carry                    # a: (bm, bk), column j at lane 0
+        amag, asgn = _signed_operand(a[:, :1], w)
+        bmag, bsgn = _signed_operand(b_ref[pl.ds(j, 1), :], w)
+        p = approx_mul(jnp.broadcast_to(amag, (bm, bn)),
+                       jnp.broadcast_to(bmag, (bm, bn)), mul_spec,
+                       fast=fast)
+        p = jax.lax.bitcast_convert_type(p, jnp.int32) * (asgn * bsgn)
+        return pltpu.roll(a, bk - 1, 1), part + p
 
-    partial = jax.lax.fori_loop(0, bk, body,
-                                jnp.zeros((bm, bn), jnp.int32))
+    _, partial = jax.lax.fori_loop(
+        0, bk, step, (a_ref[...], jnp.zeros((bm, bn), jnp.int32)))
 
     @pl.when(pl.program_id(2) == 0)
     def _init():
@@ -132,88 +119,92 @@ def _mac_matmul_kernel(a_ref, b_ref, t_ref, o_ref, *, spec: AdderSpec,
 
 
 def mac_matmul_pallas(a, b, spec: AdderSpec, mul_spec: MulSpec, *,
-                      block=(128, 128, 128), interpret: bool = True,
+                      interpret: bool, block=(128, 128, 128),
                       fast: bool = False):
-    """a: int32 (M, K); b: int32 (K, N) -> int32 (M, N), signed values
-    with magnitude < 2^(w-1)..2^(w-1) (w = ``mul_spec.n_bits``).
+    """a: int32 (M, K); b: int32 (K, N) -> int32 (M, N), operands read
+    as signed ``mul_spec.n_bits``-bit values.
 
-    Every product is one gather from the VMEM-resident signed product
-    table (exact for zero operands, so callers may zero-pad ragged K
-    tiles without changing the result); in-tile accumulation is exact
-    int32, inter-tile accumulation runs the approximate adder."""
+    Every product is ``sign(a) * sign(b) * approx(|a|, |b|)`` (zero for
+    a zero operand, so callers may zero-pad ragged K tiles without
+    changing the result); in-tile accumulation is exact int32,
+    inter-tile accumulation runs the approximate adder."""
     m, k = a.shape
     k2, n = b.shape
     assert k == k2
     bm, bn, bk = (min(block[0], m), min(block[1], n), min(block[2], k))
     assert m % bm == 0 and n % bn == 0 and k % bk == 0
-    table = jnp.asarray(signed_mul_table(mul_spec))
-    grid = (m // bm, n // bn, k // bk)
-    entries = int(table.shape[0])
     return pl.pallas_call(
         functools.partial(_mac_matmul_kernel, spec=spec,
                           mul_spec=mul_spec, fast=fast, bk=bk),
         out_shape=jax.ShapeDtypeStruct((m, n), jnp.int32),
-        grid=grid,
+        grid=(m // bm, n // bn, k // bk),
         in_specs=[
             pl.BlockSpec((bm, bk), lambda i, j, kk: (i, kk)),
             pl.BlockSpec((bk, bn), lambda i, j, kk: (kk, j)),
-            pl.BlockSpec((entries,), lambda i, j, kk: (0,)),
         ],
         out_specs=pl.BlockSpec((bm, bn), lambda i, j, kk: (i, j)),
         interpret=interpret,
-    )(a, b, table)
+        name="mac_matmul",
+    )(a, b)
 
 
 # ------------------------------------------------------ conv2d MAC --
 
-def _conv2d_kernel(q_ref, t_ref, o_ref, *, spec: AdderSpec, kh: int,
-                   kw: int, shift: int, fast: bool):
-    from repro.ax.backends import conv_taps
-    x = q_ref[0]                           # (h, w) int32 signed values
-    tables = t_ref[...]                    # (T, 2^w) int32 products
+def _conv2d(x, row0, *, spec: AdderSpec, mul_spec: MulSpec, kh: int,
+            kw: int, weights, shift: int, fast: bool, h: int, w: int):
+    rows, cols = plane_index(x.shape, row0)
     mask = jnp.uint32((1 << spec.n_bits) - 1)
     sign = jnp.uint32(1 << (spec.n_bits - 1))
+    cy, cx = kh // 2, kw // 2
+    taps = iter(weights)
     acc = None
-    for i, view in enumerate(conv_taps(jnp, x, kh, kw)):
-        p = jnp.take(tables[i], jnp.abs(view))
-        p = jnp.where(view < 0, -p, p)
-        u = jax.lax.bitcast_convert_type(p, jnp.uint32) & mask
-        acc = u if acc is None else approx_add_mod(acc, u, spec,
-                                                   fast=fast)
+    for rv in edge_views(x, 0, range(-cy, kh - cy), rows, h):
+        for view in edge_views(rv, 1, range(-cx, kw - cx), cols, w):
+            wt = next(taps)
+            # sign(w) * approx(|v|, |w|), negated for v < 0: the
+            # per-tap product column of repro.ax.mul.lut.tap_tables.
+            mag = jax.lax.bitcast_convert_type(jnp.abs(view), jnp.uint32)
+            p = approx_mul(mag, jnp.full(view.shape, abs(wt), jnp.uint32),
+                           mul_spec, fast=fast)
+            p = jax.lax.bitcast_convert_type(p, jnp.int32)
+            if wt < 0:
+                p = -p
+            p = jnp.where(view < 0, -p, p)
+            u = jax.lax.bitcast_convert_type(p, jnp.uint32) & mask
+            acc = u if acc is None else approx_add_mod(acc, u, spec,
+                                                       fast=fast)
     s = jax.lax.bitcast_convert_type((acc ^ sign) - sign, jnp.int32)
     if shift:
         s = (s + (1 << (shift - 1))) >> shift
-    o_ref[0] = s
+    return s
 
 
 @functools.partial(jax.jit,
                    static_argnames=("spec", "mul_spec", "kernel", "shift",
                                     "interpret", "fast"))
 def conv2d_mac_pallas(q, spec: AdderSpec, mul_spec: MulSpec, kernel, *,
-                      shift: int = 0, interpret: bool = True,
-                      fast: bool = False):
+                      interpret: bool, shift: int = 0, fast: bool = False):
     """q: signed int32 (..., H, W), |q| < 2^w; ``kernel`` a static
     tuple-of-tuples of integer weights with odd dims.  One program per
-    leading-batch image, the whole plane VMEM-resident, replicate-edge
-    padding — the MAC twin of ``filter_chain_pallas``."""
+    (image, row block), replicate-edge taps — the MAC twin of
+    ``filter_chain_pallas``."""
     if q.ndim < 2:
         raise ValueError(f"conv2d needs (..., H, W); got {q.shape}")
     kh = len(kernel)
     kw = len(kernel[0])
     weights = tuple(int(w) for row in kernel for w in row)
-    tables = jnp.asarray(tap_tables(mul_spec, weights))
+    for wt in weights:
+        if abs(wt) >= 1 << mul_spec.n_bits:
+            raise ValueError(
+                f"kernel weight {wt} exceeds the {mul_spec.n_bits}-bit "
+                f"multiplier operand range (|w| < {1 << mul_spec.n_bits})")
     shape = q.shape
     h, w = shape[-2:]
     b = int(np.prod(shape[:-2])) if shape[:-2] else 1
-    t_dim, entries = int(tables.shape[0]), int(tables.shape[1])
-    out = pl.pallas_call(
-        functools.partial(_conv2d_kernel, spec=spec, kh=kh, kw=kw,
-                          shift=shift, fast=fast),
-        out_shape=jax.ShapeDtypeStruct((b, h, w), jnp.int32),
-        grid=(b,),
-        in_specs=[pl.BlockSpec((1, h, w), lambda i: (i, 0, 0)),
-                  pl.BlockSpec((t_dim, entries), lambda i: (0, 0))],
-        out_specs=pl.BlockSpec((1, h, w), lambda i: (i, 0, 0)),
-        interpret=interpret,
-    )(q.reshape(b, h, w).astype(jnp.int32), tables)
+    body = functools.partial(_conv2d, spec=spec, mul_spec=mul_spec, kh=kh,
+                             kw=kw, weights=weights, shift=shift,
+                             fast=fast, h=h, w=w)
+    out = row_blocked_call(body, q.reshape(b, h, w).astype(jnp.int32),
+                           reach=kh // 2, interpret=interpret,
+                           name="conv2d_mac")
     return out.reshape(shape)

@@ -182,6 +182,27 @@ def test_corpus_sweep_acceptance():
         assert by[("accurate", name)].ssim == 1.0, name
 
 
+@pytest.mark.parametrize("backend", ("pallas", "pallas_tpu"))
+def test_run_corpus_named_backend_reaches_its_kernels(backend,
+                                                       monkeypatch):
+    """A batched workload run with an explicitly named Pallas backend
+    dispatches that backend's kernel entry, whatever the host's
+    auto-detected default is.  (The kernel is stubbed: the Mosaic one
+    runs on a TPU only.)"""
+    from repro.ax import get_backend
+    calls = []
+
+    def stub(self, q, spec, stages, *, strategy="reference"):
+        calls.append(self.name)
+        return q
+
+    monkeypatch.setattr(type(get_backend(backend)), "filter_chain", stub)
+    rows = run_corpus(kinds=("haloc_axa",), workloads=("gaussian_blur",),
+                      n_images=1, size=19, backend=backend)
+    assert len(rows) == 1
+    assert calls and set(calls) == {backend}
+
+
 def test_corpus_quality_ordering():
     """The error-compensated families beat the plain OR families on the
     blur corpus cells, mirroring the paper's Fig-5/6 ordering."""

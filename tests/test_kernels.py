@@ -120,3 +120,44 @@ def test_butterfly_matches_image_fft_stage():
     from_u = lambda u: u.astype(np.uint32).astype(np.int32)
     np.testing.assert_array_equal(np.asarray(got[0]), from_u(top_re))
     np.testing.assert_array_equal(np.asarray(got[2]), from_u(bot_re))
+
+
+# ------------------------------------------------ row-blocked stencils --
+
+STENCIL16 = AdderSpec(kind="haloc_axa", n_bits=16, lsm_bits=8, const_bits=4)
+
+
+@pytest.mark.parametrize("shape", [(2, 600, 200), (1, 520, 129)])
+@pytest.mark.parametrize("op", ("filter_chain", "conv2d"))
+def test_row_blocked_stencil_kernels(op, shape):
+    """Planes too large for one VMEM block run as row blocks with an
+    in-kernel halo (ragged H, unaligned W); the result equals the
+    padding oracles of the jax/numpy backends bit for bit."""
+    from repro.ax import get_backend
+    from repro.ax.backends import FilterStage
+    from repro.ax.mul import MulSpec
+    from repro.kernels import stencil
+    h, w = shape[-2:]
+    wp = -(-w // 128) * 128
+    assert h * wp > stencil.BLOCK_ELEMS, "shape must need several blocks"
+    rng = np.random.default_rng(7)
+    if op == "filter_chain":
+        q = rng.integers(0, 255 * 8, size=shape).astype(np.int32)
+        stages = (FilterStage(-2, (-2, -1, 0, 1, 2), (1, 4, 6, 4, 1), 4),
+                  FilterStage(-1, (-1, 0, 1), (1, 2, 1), 2),
+                  FilterStage(-2, (1, -1), (1, -1)))
+        want = get_backend("jax").filter_chain(jnp.asarray(q), STENCIL16,
+                                               stages, strategy="fused")
+        got = get_backend("pallas").filter_chain(jnp.asarray(q), STENCIL16,
+                                                 stages, strategy="fused")
+    else:
+        q = rng.integers(-255, 256, size=shape).astype(np.int32)
+        mul = MulSpec("broken_array", 8, 3, 1)
+        kernel = ((5, -7, 9), (11, 13, -6), (4, 17, 8))
+        want = get_backend("numpy").conv2d(q, STENCIL16, mul, kernel,
+                                           shift=3)
+        got = get_backend("pallas").conv2d(jnp.asarray(q), STENCIL16, mul,
+                                           kernel, shift=3,
+                                           strategy="fused")
+    np.testing.assert_array_equal(np.asarray(got),
+                                  np.asarray(want).astype(np.int32))

@@ -127,7 +127,7 @@ def test_spec_validation():
 @pytest.mark.parametrize("spec", CONFIGS, ids=lambda s: s.short_name)
 def test_mul_bit_identical_exhaustive_n8(spec):
     """Every backend x strategy agrees with the numpy reference on all
-    4^8 operand pairs."""
+    4^8 operand pairs (the Pallas backends have no lut strategy)."""
     a, b = _exhaustive_pairs(8)
     want = get_backend("numpy").mul(a, b, spec, strategy="reference")
     want = np.asarray(want).astype(np.int64)
@@ -137,6 +137,8 @@ def test_mul_bit_identical_exhaustive_n8(spec):
         be = get_backend(backend)
         x, y = (a, b) if backend == "numpy" else (aj, bj)
         for strategy in ("reference", "fused", "lut"):
+            if backend == "pallas" and strategy == "lut":
+                continue
             got = np.asarray(be.mul(x, y, spec, strategy=strategy))
             np.testing.assert_array_equal(
                 got.astype(np.int64), want,

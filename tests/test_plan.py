@@ -161,7 +161,8 @@ def test_filter_chain_pallas_rejects_batch_axis_taps():
     q = jnp.zeros((2, 8, 8), jnp.int32)
     spec = AdderSpec(kind="haloc_axa", n_bits=16, lsm_bits=8, const_bits=4)
     with pytest.raises(ValueError, match="axis"):
-        filter_chain_pallas(q, spec, (FilterStage(0, (0,), (1,)),))
+        filter_chain_pallas(q, spec, (FilterStage(0, (0,), (1,)),),
+                            interpret=True)
 
 
 # --------------------------------------- satellite: strategies wired --
