@@ -1,0 +1,10 @@
+"""On-chip benchmark of the approximate-arithmetic system.
+
+``python3 chipbench/run.py --workload <cell> --seed <n> --seconds <s>
+--trace <0|1>`` runs one cell of ``BENCHMARK.json`` on the TPU it is
+started on.  Every piece of a cell is found by its name: the
+configuration in ``configs/<config>.json`` (with its plain reference
+in ``configs/<config>.py``), the traffic mix in ``mixes/<traffic>.json``
+and each per-layer metric's reader in ``metrics/<metric>.py``.  Nothing
+here is imported by the system under test.
+"""

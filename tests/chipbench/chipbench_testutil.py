@@ -1,0 +1,71 @@
+"""A copy of the benchmark at test sizes, for CPU rehearsals.
+
+The copy holds ``chipbench/`` and a ``BENCHMARK.json`` whose mixes are
+shrunk to a few small images or GEMMs, next to a link to the program's
+``src/``; the harness finds every piece by name there, exactly as in a
+checkout.
+"""
+
+from __future__ import annotations
+
+import json
+import os
+import shutil
+
+REPO = os.path.dirname(os.path.dirname(os.path.dirname(
+    os.path.abspath(__file__))))
+
+#: Test-size parameters of each mix (merged over the mix file).
+TINY = {
+    "stream-b4-1024": {"batch": 2, "size": 40, "pool": 3, "depth": 2,
+                       "chunk": 4, "sample": 3},
+    "gemm-r50-b32": {"shapes": [[24, 200, 16], [16, 128, 8]], "ahead": 2},
+}
+
+
+def bench_copy(tmp_path, workloads=None) -> str:
+    """A test-size benchmark checkout under ``tmp_path``; its root."""
+    root = str(tmp_path / "checkout")
+    shutil.copytree(os.path.join(REPO, "chipbench"),
+                    os.path.join(root, "chipbench"),
+                    ignore=shutil.ignore_patterns("__pycache__"))
+    os.symlink(os.path.join(REPO, "src"), os.path.join(root, "src"))
+    mixes = os.path.join(root, "chipbench", "mixes")
+    for name, params in TINY.items():
+        path = os.path.join(mixes, name + ".json")
+        with open(path) as f:
+            mix = json.load(f)
+        mix.update(params)
+        with open(path, "w") as f:
+            json.dump(mix, f)
+    with open(os.path.join(REPO, "BENCHMARK.json")) as f:
+        bench = json.load(f)
+    if workloads is not None:
+        bench["workloads"] = [w for w in bench["workloads"]
+                              if w["name"] in workloads]
+    write_bench(root, bench)
+    return root
+
+
+def read_bench(root: str) -> dict:
+    with open(os.path.join(root, "BENCHMARK.json")) as f:
+        return json.load(f)
+
+
+def write_bench(root: str, bench: dict) -> None:
+    with open(os.path.join(root, "BENCHMARK.json"), "w") as f:
+        json.dump(bench, f, indent=1)
+
+
+def run_cell(root, workload, *, seed=2**31 + 11, seconds=None, trace=0,
+             control=0, backend="jax", patch=None, keep_trace=None):
+    """One in-process run of ``workload`` on the CPU; the result."""
+    from chipbench import run as run_lib
+    if seconds is None:
+        seconds = 0.3 if trace else 0.6
+    args = run_lib.parse(["--workload", workload, "--seed", str(seed),
+                          "--seconds", str(seconds), "--trace", str(trace),
+                          "--control", str(control)]
+                         + (["--keep-trace", keep_trace] if keep_trace else []))
+    return run_lib.run(args, root=root, require_tpu=False, backend=backend,
+                       patch=patch)
