@@ -19,8 +19,8 @@ Four sections:
    PR-3 MPix/s with the gate within 0.1 dB for every kind.
 4. **Telemetry overhead**: the ``repro.obs`` layer measured on the
    fast path — pristine jitted callable vs instrumented-but-disabled
-   vs fully enabled — plus a traced stream that writes the
-   ``OBS_trace.json`` / ``OBS_metrics.json`` profiling artifacts.
+   vs fully enabled — plus a stream under a profiler capture that
+   writes the ``OBS_profile/`` / ``OBS_metrics.json`` artifacts.
    ``benchmarks/check_overhead.py`` bounds the disabled overhead.
 
 All timing through ``benchmarks.timing.timeit_jax`` (compile excluded,
@@ -35,6 +35,7 @@ from __future__ import annotations
 
 from typing import Dict, List, Optional, Sequence, Tuple
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 
@@ -46,6 +47,9 @@ from repro.imgproc import (PIPELINES, compile_pipeline, compile_tiled,
 #: The megapixel benchmark's pipeline (the acceptance chain) and tile.
 MEGA_STAGES = PIPELINES["pipe_blur_sharpen_down"]
 MEGA_TILE = (256, 256)
+
+#: Where the telemetry section writes its profiler capture.
+PROFILE_DIR = "OBS_profile"
 
 
 def _pipeline_records(batches, kind: str, backend: str,
@@ -214,13 +218,16 @@ def _telemetry_records(size: int, backend: str, kind: str,
       bound (``benchmarks/check_overhead.py``) is its ``overhead_pct``
       against baseline-raw: <= 2%, asserted from these records so the
       check is same-process/same-machine and immune to host drift.
-    - ``telemetry-on``: spans + metrics enabled — the price of a
-      profiling run (informational; no bound).
+    - ``telemetry-on``: metrics enabled, so every span is built (it
+      records only under a profiler capture) — the price of a
+      telemetry run (informational; no bound).
 
-    The enabled config then streams a few batches with telemetry live
-    and writes the artifacts next to the BENCH json: ``OBS_trace.json``
-    (Chrome trace-event, load in ui.perfetto.dev) and
-    ``OBS_metrics.json`` (counters/gauges/histograms + cache stats).
+    The enabled config then streams a few batches with telemetry on
+    under a profiler capture and writes the artifacts next to the BENCH
+    json: ``OBS_profile/`` (the capture, spans and operations on one
+    clock; open it in TensorBoard's profile plugin or its trace in
+    Perfetto) and ``OBS_metrics.json`` (counters/gauges/histograms +
+    cache stats).
     """
     from repro import obs
     batch = synthetic_batch(1, size)
@@ -272,18 +279,16 @@ def _telemetry_records(size: int, backend: str, kind: str,
             "overhead_pct": overhead,
         })
 
-    # A short telemetry-enabled stream: the profiling artifacts CI
-    # uploads.  Trace + metrics land next to the BENCH json files.
+    # A short telemetry-enabled stream under a profiler capture: the
+    # profiling artifacts CI uploads, next to the BENCH json files.
     obs.reset_all()
     stream = [synthetic_batch(1, size, seed=31 + i) for i in range(4)]
-    with obs.telemetry(True):
+    with obs.telemetry(True), jax.profiler.trace(PROFILE_DIR):
         res = run_streaming(lambda b: tiled(jnp.asarray(b)), stream,
                             depth=2)
-    obs.export_chrome_trace("OBS_trace.json")
     obs.write_metrics("OBS_metrics.json")
     print(f"  traced stream: {res.mpix_per_s:.1f} MPix/s, "
-          f"{len(obs.get_tracer().events)} spans -> OBS_trace.json, "
-          f"metrics -> OBS_metrics.json")
+          f"profile -> {PROFILE_DIR}/, metrics -> OBS_metrics.json")
     obs.reset_all()
     return lines, records
 

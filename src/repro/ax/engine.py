@@ -242,8 +242,7 @@ class AxEngine:
         MAC engine (``mul_spec`` set) every product additionally runs
         the approximate multiplier."""
         with _obs.span("ax:matmul", kind=self.spec.kind,
-                       backend=self.backend.name) if _obs._ENABLED \
-                else _obs._NOOP:
+                       backend=self.backend.name):
             return self.backend.matmul(a, b, self.spec, block=block,
                                        strategy=self.strategy,
                                        mul_spec=self.mul_spec)

@@ -19,6 +19,7 @@ import hashlib
 import time
 from typing import Callable, Iterable, List, Optional, Sequence, Tuple
 
+import jax
 import numpy as np
 
 from repro.ax import default_backend_name
@@ -351,22 +352,22 @@ def run_streaming(fn: Callable, batches: Iterable[np.ndarray], *,
 
     def dispatch(batch, index: int, attempt: int) -> None:
         t = time.perf_counter()
-        if instrumented:
-            with _obs.span("stream:dispatch", batch=index,
-                           attempt=attempt):
-                fut = active[0](batch)
-            in_flight.inc()
-        else:
+        with _obs.span("stream:dispatch", batch=index, attempt=attempt):
             fut = active[0](batch)
+        if instrumented:
+            in_flight.inc()
         pending.append(_InFlight(t, fut, index, batch, attempt))
 
     def drain() -> None:
         # Draining materializes the device future on the host: THE sync
-        # point of the stream (np.asarray blocks until ready).
+        # point of the stream (np.asarray blocks until ready).  Traced,
+        # the wait for the device and the copy-out are two spans.
         ent = pending.popleft()
         try:
-            if instrumented:
-                with _obs.span("stream:drain", batch=ent.index):
+            if _obs.live():
+                with _obs.span("stream:wait", batch=ent.index):
+                    jax.block_until_ready(ent.fut)
+                with _obs.span("stream:fetch", batch=ent.index):
                     out = np.asarray(ent.fut)
             else:
                 out = np.asarray(ent.fut)

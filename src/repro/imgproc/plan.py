@@ -131,7 +131,7 @@ class CompiledPipeline:
     downs: Tuple[int, ...] = ()
 
     def __call__(self, imgs):
-        if _obs._ENABLED:
+        if _obs.live():
             with _obs.span("plan:call", stages=self.stage_names,
                            requant=self.requant,
                            backend=self.engine.backend.name):
@@ -171,23 +171,28 @@ class CompiledPipeline:
         return in_size
 
 
+def _stage_scope(ax) -> Callable:
+    """What each stage runs under, given its name.  On the traced
+    backends a ``jax.named_scope("stage:<name>")``: every HLO operation
+    the stage emits, fusions and kernels included, carries the scope in
+    its metadata, so the device trace names the stage of each
+    operation.  On the numpy host engine an ``obs.span``, which is what
+    gives drift capture its stage attribution."""
+    if ax.backend.name == "numpy":
+        return lambda name: _obs.span(f"stage:{name}")
+    return lambda name: jax.named_scope(f"stage:{name}")
+
+
 def _stage_chain(stages, ax) -> Callable:
     """requant="stage": the standalone operators back to back — each
     stage's own quantize/round/saturate runs, so the chain is
     bit-identical to per-stage workload calls."""
+    scope = _stage_scope(ax)
 
     def chain(img):
         x = img
         for name, kw_items in stages:
-            # On the jax backends this chain runs under jit: the span
-            # fires at TRACE time only (it labels compilation, and —
-            # on the numpy host engine — every per-stage execution,
-            # which is what gives drift capture its stage attribution).
-            if _obs._ENABLED:
-                with _obs.span(f"stage:{name}"):
-                    x = ops_lib.get_operator(name).fn(x, ax,
-                                                      **dict(kw_items))
-            else:
+            with scope(name):
                 x = ops_lib.get_operator(name).fn(x, ax, **dict(kw_items))
         return x
 
@@ -213,14 +218,12 @@ def _fused_chain(stages, ax) -> Callable:
     approximate adds see a different low-bit operand distribution — and
     is exactly what the PSNR gate exists to reject.)"""
     qforms = [ops_lib.get_operator(name).qform for name, _ in stages]
+    scope = _stage_scope(ax)
 
     def chain(img):
         q = jnp.asarray(img, jnp.int32) << qforms[0].in_frac
         for i, ((name, kw_items), qf) in enumerate(zip(stages, qforms)):
-            if _obs._ENABLED:
-                with _obs.span(f"stage:{name}", requant="fused"):
-                    q = qf.fn(q, ax, **dict(kw_items))
-            else:
+            with scope(name):
                 q = qf.fn(q, ax, **dict(kw_items))
             f = qf.out_frac
             if i + 1 < len(qforms):

@@ -75,4 +75,5 @@ def accumulate_pallas(terms, spec: AdderSpec, *, weights=None,
         in_specs=[pl.BlockSpec((k, bm, bn), lambda i, j: (0, i, j))],
         out_specs=pl.BlockSpec((bm, bn), lambda i, j: (i, j)),
         interpret=interpret,
+        name="accumulate",
     )(terms)

@@ -53,4 +53,5 @@ def approx_add_pallas(a, b, spec: AdderSpec, *, block=(256, 256),
         ],
         out_specs=pl.BlockSpec((bm, bn), lambda i, j: (i, j)),
         interpret=interpret,
+        name="approx_add",
     )(a, b)

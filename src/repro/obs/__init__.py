@@ -2,10 +2,14 @@
 
 Three pillars, instrumented through engine → plan → tiles → streaming:
 
-1. **Tracing** (:mod:`repro.obs.trace`): nestable, thread-safe
-   context-var spans (``obs.span("stage:blur")``) with optional
-   ``block_until_ready`` device-sync points (:func:`sync_span`),
-   exported as Chrome trace-event JSON loadable in Perfetto.
+1. **Tracing** (:mod:`repro.obs.trace`): spans (``obs.span("plan:call")``)
+   are ``jax.profiler`` annotations, so they land in the profiler's
+   trace beside the device's operations, on its clock, and record
+   whenever a capture is live.  Inside jitted code the compiled plan
+   labels each stage's operations with ``jax.named_scope("stage:<name>")``
+   instead, which the device trace carries per operation.  Capture with
+   ``jax.profiler.trace(dir)`` and open the result in Perfetto or
+   TensorBoard.
 2. **Metrics** (:mod:`repro.obs.metrics`): named counters / gauges /
    histograms (pixels processed, batches in flight, per-batch latency
    percentiles) plus a named cache-stats facade
@@ -16,18 +20,20 @@ Three pillars, instrumented through engine → plan → tiles → streaming:
    active ``(kind, m, k)`` config — the runtime counterpart of
    ``fused_psnr_gate``.
 
-Everything is ZERO-COST when disabled: one module-level flag
+Metrics and drift capture switch with one module-level flag
 (:func:`enable` / :func:`disable`, or ``REPRO_OBS=1`` in the
-environment) gates no-op fast paths for spans, instruments and drift
-capture; the disabled overhead on the megapixel streaming benchmark is
-measured and bounded by ``benchmarks/bench_imgproc.py`` (telemetry
-cell) and ``benchmarks/check_overhead.py``.
+environment); off, they are shared no-ops.  A span costs one flag test
+and the profiler's "is a capture live" test when neither is on.  The
+off overhead on the megapixel streaming benchmark is measured and
+bounded by ``benchmarks/bench_imgproc.py`` (telemetry cell) and
+``benchmarks/check_overhead.py``.
 
+    import jax
     from repro import obs
 
-    obs.enable()
-    ...run pipelines / streams...
-    obs.export_chrome_trace("trace.json")   # open in ui.perfetto.dev
+    obs.enable()                          # metrics and drift capture
+    with jax.profiler.trace("prof"):      # spans and device operations
+        ...run pipelines / streams...
     obs.write_metrics("metrics.json")
     print(obs.format_cache_stats())
 """
@@ -66,18 +72,13 @@ from repro.obs.metrics import (  # noqa: F401
     write_metrics,
 )
 from repro.obs.trace import (  # noqa: F401
-    SpanEvent,
-    Tracer,
     current_span,
     current_stack,
     disable,
     enable,
     enabled,
-    export_chrome_trace,
-    get_tracer,
-    reset,
+    live,
     span,
-    sync_span,
 )
 
 
@@ -103,22 +104,19 @@ def telemetry(on: bool = True) -> _TelemetryScope:
 
 
 def reset_all() -> None:
-    """Clear recorded spans AND metrics (cache stats are live views and
-    are not resettable from here)."""
-    reset()
+    """Clear recorded metrics (spans live in the profiler's capture;
+    cache stats are live views and are not resettable from here)."""
     reset_metrics()
 
 
 __all__ = [
     "Counter", "DriftMonitor", "DriftStatus", "Gauge", "Histogram",
-    "MetricsRegistry", "SpanEvent", "Tracer", "active_monitor",
-    "cache_names", "cache_stats", "counter", "current_span",
-    "current_stack", "disable", "enable", "enabled",
-    "export_chrome_trace", "format_cache_stats", "gauge", "get_cached",
-    "get_tracer", "histogram", "install", "installed",
-    "metrics_snapshot", "quantile", "register_lru", "registry", "reset",
-    "reset_all", "reset_metrics", "span", "sync_span", "telemetry",
-    "uninstall", "write_metrics",
+    "MetricsRegistry", "active_monitor", "cache_names", "cache_stats",
+    "counter", "current_span", "current_stack", "disable", "enable",
+    "enabled", "format_cache_stats", "gauge", "get_cached", "histogram",
+    "install", "installed", "live", "metrics_snapshot", "quantile",
+    "register_lru", "registry", "reset_all", "reset_metrics", "span",
+    "telemetry", "uninstall", "write_metrics",
 ]
 
 if os.environ.get("REPRO_OBS", "") not in ("", "0"):

@@ -1,173 +1,77 @@
-"""Context-var span tracer with Chrome-trace-event export.
+"""Spans on the profiler's clock.
 
-The tracing pillar of :mod:`repro.obs`: nestable wall-clock spans over
-the execution stack (plan stage seams, tile dispatch, engine entry
-points, the streaming runner), recorded into one process-wide
-:class:`Tracer` and exported as Chrome trace-event JSON — the format
-Perfetto (https://ui.perfetto.dev) and ``chrome://tracing`` load
-directly.
+The tracing pillar of :mod:`repro.obs`.  ``obs.span(name, **args)`` is a
+``jax.profiler.TraceAnnotation``: while a profiler capture is live
+(``jax.profiler.trace(dir)``, ``jax.profiler.start_trace``) it records
+one host event, with ``args`` as the event's stats, into the same trace
+as the device's operations and on the same clock.  Open the capture in
+Perfetto or TensorBoard's profile plugin.
 
-Zero-cost when disabled: :func:`span` checks the module-level
-:data:`_ENABLED` flag and returns a shared no-op context manager — one
-branch plus one ``with`` on an empty ``__enter__``/``__exit__`` pair —
-so instrumentation can live permanently on hot call paths.  Nesting is
-tracked through a :class:`contextvars.ContextVar` stack, which makes
-the tracer thread-safe (each thread sees its own stack; the
-double-buffered streaming runner and any worker threads record
-disjoint, correctly-nested spans) while the event list itself is
-guarded by a lock.
+A span records whenever a capture is live, whether or not telemetry is
+on.  With no capture and telemetry off, :func:`span` returns one shared
+no-op after a flag test and the profiler's own "is a capture live" test
+(a fraction of a microsecond), so spans can live on hot call paths.
+Telemetry (:func:`enable`, ``REPRO_OBS=1``) switches the counters,
+gauges, histograms and drift capture; a live span also pushes its name
+on a context-var stack, which gives drift capture its stage label
+(:func:`current_stack`).
 
-Semantics on the jitted backends: a span around code inside a
-``jax.jit``/``lax.scan`` trace fires at TRACE time (the first call) and
-never again — it measures tracing/compilation, not steady-state device
-compute.  Spans around the *dispatch* of a compiled callable measure
-host-side dispatch; pair them with :func:`sync_span` (an explicit
-``block_until_ready`` point) where a host sync already happens to see
-true device latency.
+Inside ``jax.jit`` a span would fire once, at trace time, and label no
+device operation.  Traced code labels its operations with
+``jax.named_scope`` instead (the compiled plan names each stage
+``stage:<name>``): every HLO operation, fusions included, carries the
+scope in its metadata, and the device trace shows it.
 
+    import jax
     from repro import obs
 
-    obs.enable()
-    with obs.span("stage:blur", kind="haloc_axa"):
-        ...
-    obs.export_chrome_trace("trace.json")    # load in Perfetto
+    with jax.profiler.trace("/tmp/prof"):
+        with obs.span("stream:run", batches=8):
+            ...
 """
 
 from __future__ import annotations
 
 import contextvars
-import dataclasses
-import json
-import os
-import threading
-import time
-from typing import Any, Dict, List, Optional, Tuple
+from typing import Optional, Tuple
 
-#: THE module-level telemetry flag (shared by the metrics fast paths and
-#: the drift-capture hooks).  Flip via :func:`enable`/:func:`disable`.
+from jax.profiler import TraceAnnotation
+
+#: THE module-level telemetry flag (metrics, drift capture, and the
+#: spans of the call sites that test it).  Flip via
+#: :func:`enable`/:func:`disable`.
 _ENABLED = False
+
+#: Whether a profiler capture is live (the profiler's own flag test).
+_capturing = TraceAnnotation.is_enabled
 
 
 def enabled() -> bool:
-    """Whether telemetry (spans, metrics, drift capture) is live."""
+    """Whether telemetry (metrics, drift capture) is on."""
     return _ENABLED
 
 
 def enable() -> None:
-    """Turn telemetry on (spans/metrics record, drift capture runs)."""
+    """Turn telemetry on (metrics record, drift capture runs)."""
     global _ENABLED
     _ENABLED = True
 
 
 def disable() -> None:
-    """Turn telemetry off — every hook degrades to its no-op fast path.
-    Recorded events/metrics are kept until :func:`reset`."""
+    """Turn telemetry off; recorded metrics are kept until reset."""
     global _ENABLED
     _ENABLED = False
 
 
-@dataclasses.dataclass(frozen=True)
-class SpanEvent:
-    """One finished span: times are seconds relative to the tracer
-    epoch; ``depth``/``parent`` encode the nesting at record time."""
+def live() -> bool:
+    """Whether a span records: telemetry is on or a capture is live."""
+    return _ENABLED or _capturing()
 
-    name: str
-    ts: float                 # start, s since Tracer epoch
-    dur: float                # wall seconds
-    tid: int                  # small per-tracer thread index
-    depth: int                # 0 = top level
-    parent: Optional[str]     # enclosing span name (None at top level)
-    args: Dict[str, Any]
-
-
-def _json_safe(v):
-    if isinstance(v, (bool, int, float, str)) or v is None:
-        return v
-    return str(v)
-
-
-class Tracer:
-    """Process-wide span sink.  Appends are lock-guarded (cheap: one
-    tuple build per finished span); reads snapshot under the lock."""
-
-    def __init__(self) -> None:
-        self._lock = threading.Lock()
-        self._events: List[SpanEvent] = []
-        self._tids: Dict[int, int] = {}
-        self.epoch = time.perf_counter()
-
-    def _tid(self, ident: int) -> int:
-        tid = self._tids.get(ident)
-        if tid is None:
-            tid = self._tids[ident] = len(self._tids)
-        return tid
-
-    def record(self, name: str, t0: float, dur: float, depth: int,
-               parent: Optional[str], args: Dict[str, Any]) -> None:
-        ev = SpanEvent(name=name, ts=t0 - self.epoch, dur=dur,
-                       tid=self._tid(threading.get_ident()),
-                       depth=depth, parent=parent, args=args)
-        with self._lock:
-            self._events.append(ev)
-
-    @property
-    def events(self) -> Tuple[SpanEvent, ...]:
-        with self._lock:
-            return tuple(self._events)
-
-    def clear(self) -> None:
-        with self._lock:
-            self._events.clear()
-            self._tids.clear()
-            self.epoch = time.perf_counter()
-
-    # ------------------------------------------------- chrome export --
-
-    def chrome_trace(self) -> Dict[str, Any]:
-        """The trace as a Chrome trace-event object: ``"X"`` (complete)
-        events with microsecond ``ts``/``dur``, plus thread-name
-        metadata — loadable in Perfetto / ``chrome://tracing``."""
-        pid = os.getpid()
-        events: List[Dict[str, Any]] = []
-        with self._lock:
-            tids = dict(self._tids)
-            snapshot = list(self._events)
-        for ident, tid in tids.items():
-            events.append({"ph": "M", "pid": pid, "tid": tid,
-                           "name": "thread_name",
-                           "args": {"name": f"thread-{ident}"}})
-        for e in snapshot:
-            events.append({
-                "name": e.name, "cat": "repro", "ph": "X",
-                "ts": e.ts * 1e6, "dur": e.dur * 1e6,
-                "pid": pid, "tid": e.tid,
-                "args": {**{k: _json_safe(v) for k, v in e.args.items()},
-                         "depth": e.depth,
-                         "parent": e.parent or ""},
-            })
-        return {"traceEvents": events, "displayTimeUnit": "ms"}
-
-    def export_chrome_trace(self, path: str) -> str:
-        with open(path, "w") as f:
-            json.dump(self.chrome_trace(), f)
-        return path
-
-
-_TRACER = Tracer()
 
 #: Per-context stack of open span names (nesting + stage attribution
 #: for the drift monitor's engine capture).
 _STACK: contextvars.ContextVar[Tuple[str, ...]] = \
     contextvars.ContextVar("repro_obs_span_stack", default=())
-
-
-def get_tracer() -> Tracer:
-    return _TRACER
-
-
-def reset() -> None:
-    """Drop all recorded spans and re-zero the trace epoch."""
-    _TRACER.clear()
 
 
 def current_stack() -> Tuple[str, ...]:
@@ -182,7 +86,7 @@ def current_span() -> Optional[str]:
 
 
 class _NoopSpan:
-    """The disabled fast path: a shared, state-free context manager."""
+    """The off fast path: a shared, state-free context manager."""
 
     __slots__ = ()
 
@@ -200,58 +104,33 @@ _NOOP = _NoopSpan()
 
 
 class _Span:
-    __slots__ = ("name", "args", "_t0", "_tok", "_depth", "_parent")
+    __slots__ = ("name", "_args", "_annotation", "_tok")
 
-    def __init__(self, name: str, args: Dict[str, Any]):
+    def __init__(self, name: str, args):
         self.name = name
-        self.args = args
+        self._args = args
 
     def __enter__(self):
-        stack = _STACK.get()
-        self._depth = len(stack)
-        self._parent = stack[-1] if stack else None
-        self._tok = _STACK.set(stack + (self.name,))
-        self._t0 = time.perf_counter()
+        self._tok = _STACK.set(_STACK.get() + (self.name,))
+        self._annotation = TraceAnnotation(self.name, **self._args)
+        self._annotation.__enter__()
         return self
 
     def set(self, **kw):
-        """Attach extra args to the span before it closes."""
-        self.args.update(kw)
+        """Attach extra stats to the span's event before it closes."""
+        self._annotation.set_metadata(**kw)
 
     def __exit__(self, *exc):
-        dur = time.perf_counter() - self._t0
+        self._annotation.__exit__(*exc)
         _STACK.reset(self._tok)
-        _TRACER.record(self.name, self._t0, dur, self._depth,
-                       self._parent, self.args)
         return False
 
 
 def span(name: str, **args):
-    """A wall-clock span context manager; no-op when telemetry is off.
-
-    ``args`` become the Chrome trace event's ``args`` (JSON-coerced on
-    export).  Spans nest; nesting is per-thread/per-context."""
-    if not _ENABLED:
+    """A profiler-annotation span; the shared no-op unless telemetry is
+    on or a capture is live.  ``args`` become the event's stats (values
+    that are not numbers or strings are recorded as strings).  Spans
+    nest per thread and per context."""
+    if not (_ENABLED or _capturing()):
         return _NOOP
     return _Span(name, args)
-
-
-def sync_span(value, name: str = "device_sync", **args):
-    """An explicit device-sync point: ``jax.block_until_ready(value)``
-    under a span, returning ``value``.
-
-    When telemetry is DISABLED this returns ``value`` untouched — no
-    sync is forced — so it must only be placed where the caller either
-    tolerates or already performs a sync.  When enabled, the span's
-    duration is the true device-compute drain the host would otherwise
-    observe lumped into its next blocking read."""
-    if not _ENABLED:
-        return value
-    import jax
-    with span(name, **args):
-        return jax.block_until_ready(value)
-
-
-def export_chrome_trace(path: str) -> str:
-    """Write the process tracer's Chrome trace-event JSON to ``path``."""
-    return _TRACER.export_chrome_trace(path)

@@ -1,13 +1,13 @@
 """repro.obs: spans, metrics, cache stats, and quality-drift telemetry.
 
-Covers the three pillars plus their integration seams: span
-nesting/ordering and the Chrome trace-event schema, histogram
+Covers the three pillars plus their integration seams: spans read
+back from a CPU profiler capture (nesting, stats, threads), histogram
 percentile math, the named cache-stats facade over the package's
 ``lru_cache`` sites, the drift monitor (matched config stays quiet,
 mis-budgeted config trips), the engine shadow-capture path, the
 extended ``StreamResult`` latency summary, and — the contract the
-whole design hangs on — that DISABLED telemetry records nothing and
-returns shared no-op objects.
+whole design hangs on — that with telemetry off and no capture live
+nothing records and shared no-op objects are returned.
 """
 
 import json
@@ -33,30 +33,31 @@ def fresh_obs():
 
 # ------------------------------------------------------------- spans --
 
-def test_span_nesting_order_and_parents(fresh_obs):
-    with obs.span("outer", label="a"):
-        assert obs.current_span() == "outer"
-        with obs.span("inner"):
-            assert obs.current_stack() == ("outer", "inner")
+def test_span_nesting_order_and_parents(fresh_obs, capture):
+    with capture() as cap:
+        with obs.span("outer", label="a"):
+            assert obs.current_span() == "outer"
+            with obs.span("inner"):
+                assert obs.current_stack() == ("outer", "inner")
     assert obs.current_stack() == ()
-    events = obs.get_tracer().events
-    # Inner CLOSES first, so it records first; nesting is in the fields.
-    assert [(e.name, e.depth, e.parent) for e in events] == \
-        [("inner", 1, "outer"), ("outer", 0, None)]
-    outer = events[1]
-    assert outer.args == {"label": "a"}
-    inner = events[0]
-    assert inner.ts >= outer.ts
-    assert inner.dur <= outer.dur
+    outer, = cap.named("outer")
+    inner, = cap.named("inner")
+    # Nesting is in the profiler's clock: inner lies inside outer, on
+    # the same thread.
+    assert outer.start_ns <= inner.start_ns <= inner.end_ns <= outer.end_ns
+    assert inner.thread == outer.thread
+    assert outer.stats == {"label": "a"}
+    assert inner.stats == {}
 
 
-def test_span_set_attaches_args(fresh_obs):
-    with obs.span("s") as sp:
-        sp.set(tiles=9)
-    assert obs.get_tracer().events[0].args == {"tiles": 9}
+def test_span_set_attaches_args(fresh_obs, capture):
+    with capture() as cap:
+        with obs.span("s") as sp:
+            sp.set(tiles=9)
+    assert cap.named("s")[0].stats == {"tiles": 9}
 
 
-def test_span_threads_get_disjoint_stacks(fresh_obs):
+def test_span_threads_get_disjoint_stacks(fresh_obs, capture):
     import threading
     seen = {}
 
@@ -67,45 +68,41 @@ def test_span_threads_get_disjoint_stacks(fresh_obs):
         with obs.span("worker-span"):
             seen["inner"] = obs.current_stack()
 
-    with obs.span("main-span"):
-        t = threading.Thread(target=worker)
-        t.start()
-        t.join()
+    with capture() as cap:
+        with obs.span("main-span"):
+            t = threading.Thread(target=worker)
+            t.start()
+            t.join()
     assert seen["stack"] == ()
     assert seen["inner"] == ("worker-span",)
-    tids = {e.name: e.tid for e in obs.get_tracer().events}
-    assert tids["worker-span"] != tids["main-span"]
+    worker_ev, = cap.named("worker-span")
+    main_ev, = cap.named("main-span")
+    assert worker_ev.thread != main_ev.thread
 
 
-def test_chrome_trace_schema(fresh_obs, tmp_path):
-    with obs.span("outer", kind="haloc_axa", shape=(4, 64)):
-        with obs.span("inner"):
-            pass
-    path = tmp_path / "trace.json"
-    obs.export_chrome_trace(str(path))
-    doc = json.loads(path.read_text())
-    assert doc["displayTimeUnit"] == "ms"
-    events = doc["traceEvents"]
-    meta = [e for e in events if e["ph"] == "M"]
-    spans = [e for e in events if e["ph"] == "X"]
-    assert meta and meta[0]["name"] == "thread_name"
-    assert len(spans) == 2
-    for e in spans:
-        # The complete-event shape Perfetto requires.
-        assert set(e) >= {"name", "ph", "ts", "dur", "pid", "tid", "args"}
-        assert e["ts"] >= 0 and e["dur"] >= 0
-        # args must be JSON-primitive (the tuple arg was coerced).
-        for v in e["args"].values():
-            assert isinstance(v, (bool, int, float, str))
-    by_name = {e["name"]: e for e in spans}
-    assert by_name["inner"]["args"]["parent"] == "outer"
-    assert by_name["outer"]["args"]["depth"] == 0
-
-
-def test_sync_span_disabled_is_identity():
+def test_span_records_under_a_capture_with_telemetry_off(capture):
     obs.disable()
-    x = object()
-    assert obs.sync_span(x) is x
+    with capture() as cap:
+        assert obs.live()
+        with obs.span("stream:dispatch", batch=3, shape=(4, 64)):
+            assert obs.current_stack() == ("stream:dispatch",)
+    assert not obs.live()
+    ev, = cap.named("stream:dispatch")
+    assert ev.stats == {"batch": 3, "shape": "(4, 64)"}
+    assert ev.end_ns >= ev.start_ns
+
+
+def test_span_off_records_nothing_and_is_the_shared_noop(capture):
+    obs.disable()
+    assert not obs.live()
+    off = obs.span("decided-off", x=1)
+    assert off is obs.span("other")
+    # Whether a span records is decided when it is made: the no-op
+    # records nothing even when entered under a capture.
+    with capture() as cap:
+        with off:
+            assert obs.current_stack() == ()
+    assert not cap.named("decided-off")
 
 
 # ----------------------------------------------------------- metrics --
@@ -289,10 +286,9 @@ def test_disabled_span_is_shared_noop():
     obs.disable()
     s1, s2 = obs.span("a"), obs.span("b", x=1)
     assert s1 is s2  # ONE shared object, no allocation per call
-    n_before = len(obs.get_tracer().events)
+    s1.set(tiles=3)  # dropped
     with obs.span("not-recorded"):
         assert obs.current_stack() == ()  # stack untouched
-    assert len(obs.get_tracer().events) == n_before
 
 
 def test_disabled_instruments_are_shared_noop():
@@ -341,18 +337,38 @@ def test_run_streaming_records_latencies_without_telemetry():
     assert r.p95_s >= r.p50_s
 
 
-def test_run_streaming_metrics_when_enabled(fresh_obs):
+def test_run_streaming_metrics_when_enabled(fresh_obs, capture):
     batches = [np.zeros((1, 8, 8), np.uint8) for _ in range(4)]
-    run_streaming(lambda b: b, batches, depth=2)
+    with capture() as cap:
+        run_streaming(lambda b: b, batches, depth=2)
     snap = obs.metrics_snapshot()
     assert snap["counters"]["stream.batches"] == 4
     assert snap["counters"]["stream.pixels"] == 4 * 64
     assert snap["histograms"]["stream.batch_seconds"]["count"] == 4
     assert snap["gauges"]["stream.batches_in_flight"]["value"] == 0
     assert snap["gauges"]["stream.batches_in_flight"]["high_water"] == 2
-    names = [e.name for e in obs.get_tracer().events]
-    assert names.count("stream:dispatch") == 4
-    assert names.count("stream:drain") == 4
+    for name in ("stream:dispatch", "stream:wait", "stream:fetch"):
+        assert sorted(e.stats["batch"] for e in cap.named(name)) == \
+            [0, 1, 2, 3]
+
+
+def test_run_streaming_spans_under_capture_without_telemetry(capture):
+    import jax.numpy as jnp
+    obs.disable()
+    batches = [np.full((1, 8, 8), i, np.uint8) for i in range(3)]
+    with capture() as cap:
+        res = run_streaming(lambda b: jnp.asarray(b) + 1, batches, depth=2)
+    for i, out in enumerate(res.outputs):
+        np.testing.assert_array_equal(out, batches[i] + 1)
+    # The drain is split: the wait for the device, then the copy-out.
+    waits = {e.stats["batch"]: e for e in cap.named("stream:wait")}
+    fetches = {e.stats["batch"]: e for e in cap.named("stream:fetch")}
+    dispatches = {e.stats["batch"]: e for e in cap.named("stream:dispatch")}
+    assert sorted(waits) == sorted(fetches) == sorted(dispatches) == [0, 1, 2]
+    for i in range(3):
+        assert dispatches[i].end_ns <= waits[i].start_ns
+        assert waits[i].end_ns <= fetches[i].start_ns
+    assert not obs.metrics_snapshot()["counters"]
 
 
 # ------------------------------------------------- satellite behavior --

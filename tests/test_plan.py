@@ -72,6 +72,26 @@ def test_pipeline_compile_cache_round_trip():
     assert p3 is not p1
 
 
+@pytest.mark.parametrize("requant", ["stage", "fused"])
+def test_plan_stages_scope_their_hlo_operations(requant):
+    """Each stage runs under ``jax.named_scope("stage:<name>")``, so the
+    compiled module's operations carry their stage in their metadata
+    (and the device trace names the stage of each operation)."""
+    import re
+
+    import jax
+    pipe = compile_pipeline(PIPELINES["pipe_blur_sharpen_down"],
+                            kind="haloc_axa", backend="jax",
+                            requant=requant)
+    text = pipe.fn.lower(jax.ShapeDtypeStruct((2, 32, 32), jnp.uint8)
+                         ).compile().as_text()
+    scopes = set()
+    for op_name in re.findall(r'op_name="([^"]*)"', text):
+        scopes.update(re.findall(r"stage:\w+", op_name))
+    assert scopes == {"stage:gaussian_blur", "stage:sharpen",
+                      "stage:downsample2x"}
+
+
 def test_pipeline_numpy_backend_matches_jax():
     stages = PIPELINES["pipe_blur_sobel"]
     out_np = run_pipeline(stages, BATCH, kind="haloc_axa",

@@ -573,9 +573,10 @@ def test_plan_executor_end_to_end():
 
 # -------------------------------------------------------- observability --
 
-def test_serving_metrics_and_spans(fresh_obs):
-    rep, _, _ = _traffic_cell(1200.0, n=120, seed=6, depth=12,
-                              backlog_s=0.010)
+def test_serving_metrics_and_spans(fresh_obs, capture):
+    with capture() as cap:
+        rep, _, _ = _traffic_cell(1200.0, n=120, seed=6, depth=12,
+                                  backlog_s=0.010)
     snap = obs.metrics_snapshot(prefix="serve.")
     c = snap["counters"]
     assert c["serve.completed"] == len(rep.completed)
@@ -590,18 +591,21 @@ def test_serving_metrics_and_spans(fresh_obs):
     assert all(k.startswith("serve.") for t in ("counters", "gauges",
                                                 "histograms")
                for k in snap[t])
-    names = {e.name for e in obs.get_tracer().events}
+    names = {e.name for e in cap.events}
     assert {"serve:submit", "serve:batch", "serve:execute"} <= names
 
 
-def test_serving_is_zero_cost_when_telemetry_off():
+def test_serving_is_zero_cost_when_telemetry_off(capture):
     obs.reset_all()
     assert not obs.enabled()
-    rep, _, _ = _traffic_cell(100.0, n=30, seed=8)
+    # The serving layer's spans and metrics switch with telemetry: a
+    # live capture alone records none of them.
+    with capture() as cap:
+        rep, _, _ = _traffic_cell(100.0, n=30, seed=8)
     assert len(rep.completed) == 30
     snap = obs.metrics_snapshot()
     assert not any(k.startswith("serve.") for k in snap["counters"])
-    assert obs.get_tracer().events == ()
+    assert not [e for e in cap.events if e.name.startswith("serve:")]
 
 
 def test_metrics_snapshot_prefix_filter(fresh_obs):

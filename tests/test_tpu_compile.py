@@ -128,3 +128,16 @@ def test_compiles_for_v5e(case, one_chip):
             for s, d in shapes]
     compiled = jax.jit(fn).lower(*args).compile()
     assert "tpu_custom_call" in compiled.as_text()
+
+
+@pytest.mark.parametrize("case,kernel", [("accumulate_k9_1024", "accumulate"),
+                                         ("fused_add_1024", "approx_add")])
+def test_kernel_is_named_in_the_compiled_module(case, kernel, one_chip):
+    """The ``pallas_call`` carries its ``name``: XLA names the Mosaic
+    custom call after it, and so does the device trace."""
+    import re
+    fn, shapes = CASES[case]()
+    args = [jax.ShapeDtypeStruct(s, d, sharding=one_chip)
+            for s, d in shapes]
+    text = jax.jit(fn).lower(*args).compile().as_text()
+    assert re.search(rf"%{kernel}(\.\d+)? = .*tpu_custom_call", text)
