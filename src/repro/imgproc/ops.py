@@ -32,6 +32,7 @@ import dataclasses
 from typing import Callable, Dict, Optional, Tuple, Union
 
 import jax.numpy as jnp
+from jax import lax
 
 from repro.ax.backends import FilterStage
 from repro.ax.engine import AxEngine, make_engine
@@ -344,16 +345,34 @@ def brightness(img, ax: AxEngine, delta: float = 37.0):
     return _finish_q(_brightness_q(_q(img, e.fmt), ax, delta), _F_BRIGHT)
 
 
+def phases2x(q):
+    """The four 2x2 phase planes of ``q[..., :h, :w]`` (``h``, ``w`` the
+    even crop), as ``q[..., i::2, j::2]`` for (i, j) = (0, 0), (0, 1),
+    (1, 0), (1, 1), in that order.
+
+    No strided indexing: JAX lowers a strided index with a non-zero
+    start to a ``gather``, one point gather per output pixel on a TPU.
+    Each row phase is a strided ``lax.slice`` over rows; its columns are
+    split by a reshape to (w/2, 2) pairs, which a TPU deinterleaves far
+    faster than it runs a slice strided across lanes."""
+    lead = q.shape[:-2]
+    h = q.shape[-2] & ~1
+    w = q.shape[-1] & ~1
+    ones = (1,) * len(lead)
+    planes = []
+    for i in (0, 1):
+        rows = lax.slice(q, (0,) * len(lead) + (i, 0), lead + (h, w),
+                         ones + (2, 1))
+        pairs = rows.reshape(lead + (h // 2, w // 2, 2))
+        planes += [pairs[..., 0], pairs[..., 1]]
+    return tuple(planes)
+
+
 def _downsample2x_q(q, ax: AxEngine):
     """2x box core: the four phase planes of each 2x2 quad are one fused
     4-term accumulation with an exact /4 rounding shift."""
     e = _with_frac(ax, _F_DOWN)
-    h = q.shape[-2] & ~1
-    w = q.shape[-1] & ~1
-    q = q[..., :h, :w]
-    phases = jnp.stack([q[..., 0::2, 0::2], q[..., 0::2, 1::2],
-                        q[..., 1::2, 0::2], q[..., 1::2, 1::2]])
-    return e.accumulate_signed(phases, shift=2)
+    return e.accumulate_signed(jnp.stack(phases2x(q)), shift=2)
 
 
 @register_operator("downsample2x", reference.downsample2x,

@@ -92,6 +92,73 @@ def test_plan_stages_scope_their_hlo_operations(requant):
                       "stage:downsample2x"}
 
 
+#: Even and odd planes, with none, one and two leading batch dims.
+PHASE_SHAPES = [(8, 10), (7, 9), (3, 8, 11), (2, 9, 12), (2, 2, 7, 6)]
+
+
+@pytest.mark.parametrize("array", ["numpy", "jax"])
+@pytest.mark.parametrize("shape", PHASE_SHAPES, ids=str)
+def test_phases2x_are_the_strided_views(shape, array):
+    """The downsample's phase planes are ``x[..., i::2, j::2]`` of the
+    even crop, in the order (0, 0), (0, 1), (1, 0), (1, 1), for numpy
+    inputs and jax arrays alike."""
+    from repro.imgproc.ops import phases2x
+    x = np.arange(np.prod(shape), dtype=np.int32).reshape(shape)
+    h, w = shape[-2] & ~1, shape[-1] & ~1
+    planes = phases2x(jnp.asarray(x) if array == "jax" else x)
+    assert len(planes) == 4
+    for plane, (i, j) in zip(planes, ((0, 0), (0, 1), (1, 0), (1, 1))):
+        np.testing.assert_array_equal(np.asarray(plane),
+                                      x[..., :h, :w][..., i::2, j::2])
+
+
+@pytest.fixture(scope="module")
+def img_reference():
+    """The ``haloc16-img`` configuration's plain reference, for any
+    chain of its stages."""
+    import json
+    import os
+
+    from chipbench.cells import load_module
+    base = os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "chipbench", "configs")
+    ref = load_module(os.path.join(base, "haloc16-img.py"),
+                      "haloc16_img_ref")
+    with open(os.path.join(base, "haloc16-img.json")) as f:
+        cfg = json.load(f)
+    return lambda imgs, stages: np.asarray(ref.reference(
+        imgs, dict(cfg, pipeline=list(stages))))
+
+
+@pytest.mark.parametrize("backend", ["jax", "numpy"])
+@pytest.mark.parametrize("stages", [("downsample2x",),
+                                    PIPELINES["pipe_blur_sharpen_down"]],
+                         ids=["downsample2x", "pipe_blur_sharpen_down"])
+@pytest.mark.parametrize("shape", PHASE_SHAPES, ids=str)
+def test_downsample_bit_identical_to_plain_reference(shape, stages,
+                                                     backend,
+                                                     img_reference):
+    """``downsample2x`` alone and the blur -> sharpen -> downsample
+    chain (fused requant) equal the benchmark's plain integer reference
+    bit for bit for HALOC-AxA N=16, on even and odd planes with any
+    leading batch dims."""
+    imgs = np.random.default_rng(sum(shape)).integers(
+        0, 256, shape, dtype=np.uint8)
+    want = img_reference(imgs, stages)
+    if stages == ("downsample2x",):
+        from repro.imgproc import downsample2x
+        from repro.imgproc.ops import make_image_engine
+        got = downsample2x(imgs, make_image_engine("haloc_axa",
+                                                   backend=backend))
+    else:
+        pipe = compile_pipeline(stages, kind="haloc_axa", backend=backend,
+                                requant="fused")
+        got = pipe.chain(imgs)
+    got = np.asarray(got)
+    assert got.dtype == np.uint8 and got.shape == want.shape
+    np.testing.assert_array_equal(got, want)
+
+
 def test_pipeline_numpy_backend_matches_jax():
     stages = PIPELINES["pipe_blur_sobel"]
     out_np = run_pipeline(stages, BATCH, kind="haloc_axa",

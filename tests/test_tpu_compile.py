@@ -130,6 +130,21 @@ def test_compiles_for_v5e(case, one_chip):
     assert "tpu_custom_call" in compiled.as_text()
 
 
+@pytest.mark.parametrize("case", ["pipe_blur_sharpen_down",
+                                  "pipe_blur_sharpen_down_tiled"])
+def test_downsample_compiles_without_gathers(case, one_chip):
+    """The downsample's 2x2 phases are strided slices: a strided index
+    with a non-zero start would lower to a point gather per output
+    pixel, which dominated the chip's time for this pipeline."""
+    import re
+    fn, shapes = CASES[case]()
+    args = [jax.ShapeDtypeStruct(s, d, sharding=one_chip)
+            for s, d in shapes]
+    text = jax.jit(fn).lower(*args).compile().as_text()
+    assert not re.search(r"\bgather\(", text)
+    assert re.search(r"\bslice\(", text)
+
+
 @pytest.mark.parametrize("case,kernel", [("accumulate_k9_1024", "accumulate"),
                                          ("fused_add_1024", "approx_add")])
 def test_kernel_is_named_in_the_compiled_module(case, kernel, one_chip):
