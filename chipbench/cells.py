@@ -1,12 +1,18 @@
 """Find a cell's pieces by the names ``BENCHMARK.json`` gives them.
 
 A cell (an entry of ``workloads``) names a configuration and a traffic
-mix.  Its files are ``chipbench/configs/<config>.json`` (the
-configuration as it is run), ``chipbench/configs/<config>.py`` (its
-plain reference), ``chipbench/mixes/<traffic>.json`` (the mix's
-parameters) and ``chipbench/metrics/<metric>.py`` for each per-layer
-metric the cell reports.  Adding a cell, a configuration, a mix or a
-metric adds files and entries; nothing here changes.
+mix.  Its files, all under ``chipbench/``, are
+``configs/<config>.json`` (the configuration as it is run),
+``configs/<config>.py`` (its plain reference),
+``system/<system>.py`` for the configuration's ``system`` key (its
+``build(cfg, backend)`` makes the system under test),
+``mixes/<traffic>.json`` (the mix's parameters),
+``loop/<loop>.py`` for the mix's ``loop`` key (its ``LOOP``, a
+:class:`chipbench.loops.Loop`, is the generator) and
+``metrics/<metric>.py`` for each per-layer metric the cell reports.
+Adding a cell, a configuration, a system, a mix, a loop or a metric
+adds files and entries; nothing here changes.  Every file is loaded
+here, before any device work, and a missing one is a :class:`CellError`.
 """
 
 from __future__ import annotations
@@ -16,6 +22,8 @@ import importlib.util
 import json
 import os
 from typing import Dict, List
+
+from chipbench import loops
 
 PACKAGE = "chipbench"
 
@@ -40,13 +48,26 @@ def _load_json(path: str) -> dict:
         return json.load(f)
 
 
+def _piece(base: str, kind: str, name, attr: str):
+    """``attr`` of ``<base>/<kind>/<name>.py``."""
+    if not isinstance(name, str):
+        raise CellError(f"no {kind} named: {name!r}")
+    module = load_module(os.path.join(base, kind, name + ".py"),
+                         f"{PACKAGE}_{kind}_{name}")
+    if not hasattr(module, attr):
+        raise CellError(f"{kind}/{name}.py defines no {attr}")
+    return getattr(module, attr)
+
+
 @dataclasses.dataclass
 class Cell:
     name: str
     chips: int
     config: dict
     reference: object          # the config's plain reference module
+    system: object             # build(cfg, backend) of the config's system
     mix: dict
+    loop: type                 # the mix's loops.Loop subclass
     end_to_end: List[dict]     # the BENCHMARK.json entries it reports
     per_layer: List[dict]
     readers: Dict[str, object]  # per-layer metric name -> reader module
@@ -74,7 +95,11 @@ def resolve(root: str, workload: str) -> Cell:
     reference = load_module(os.path.join(base, "configs",
                                          w["config"] + ".py"),
                             f"{PACKAGE}_ref_{w['config']}")
+    system = _piece(base, "system", config.get("system"), "build")
     mix = _load_json(os.path.join(base, "mixes", w["traffic"] + ".json"))
+    loop = _piece(base, "loop", mix.get("loop"), "LOOP")
+    if not (isinstance(loop, type) and issubclass(loop, loops.Loop)):
+        raise CellError(f"loop/{mix['loop']}.py: LOOP is not a Loop")
     e2e = [m for m in bench["end_to_end"] if _reports(m, workload)]
     names = {m["name"] for m in e2e}
     layer = [m for m in bench["per_layer"]
@@ -83,5 +108,5 @@ def resolve(root: str, workload: str) -> Cell:
         os.path.join(base, "metrics", m["name"] + ".py"),
         f"{PACKAGE}_metric_{m['name']}") for m in layer}
     return Cell(name=workload, chips=int(w["chips"]), config=config,
-                reference=reference, mix=mix, end_to_end=e2e,
-                per_layer=layer, readers=readers)
+                reference=reference, system=system, mix=mix, loop=loop,
+                end_to_end=e2e, per_layer=layer, readers=readers)

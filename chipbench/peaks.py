@@ -26,10 +26,11 @@ def peaks(device_kind: str) -> dict:
                        f"{device_kind!r}; known: {sorted(PEAKS)}") from None
 
 
-def bound_seconds(ops: float, nbytes: float, device_kind: str):
-    """The least time the chip could take for ``ops`` integer operations
-    moving ``nbytes``: (seconds, which bound binds)."""
+def bound_seconds(ops: float, nbytes: float, device_kind: str,
+                  peak: str = "int8_ops"):
+    """The least time the chip could take for ``ops`` operations at the
+    peak rate ``peak`` moving ``nbytes``: (seconds, which bound binds)."""
     p = peaks(device_kind)
-    t_ops = ops / p["int8_ops"]
+    t_ops = ops / p[peak]
     t_bytes = nbytes / p["hbm_bytes_per_s"]
     return (t_ops, "ops") if t_ops >= t_bytes else (t_bytes, "bytes")

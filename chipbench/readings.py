@@ -49,24 +49,45 @@ def work_of(kernel: str):
                        f"chipbench_work_{kernel}").work
 
 
+def _least_seconds(r: Reading, work: str, peak: str) -> float:
+    """The least time the chip needs for the logical work
+    (``work/<work>.py``) of every call of the window, at ``peak``."""
+    count = work_of(work)
+    return sum(peaks.bound_seconds(*count(shape, r.config), r.device_kind,
+                                   peak)[0] for shape in r.calls)
+
+
 def roofline(r: Reading, kernel: str) -> Optional[float]:
     """Percent of its roofline that ``kernel`` reached: the least time
     the chip needs for the kernel's logical work in every call of the
     window (``work/<kernel>.py``), over the summed device time of the
-    kernel's operations that start in the window.  ``None`` where the
-    trace holds none of them or the work is nil, or off the chips of
-    the peak table (the run itself refuses an unknown chip)."""
+    kernel's operations inside the window.  An operation that the
+    trace's clocks put across an edge of the window counts its part
+    inside: the first call's kernel can appear to start some tens of
+    microseconds before the window's span that dispatched it.  ``None``
+    where the trace holds none of them or the work is nil, or off the
+    chips of the peak table (the run itself refuses an unknown chip)."""
     if r.device_kind not in peaks.PEAKS:
         return None
-    work = work_of(kernel)
-    bound = sum(peaks.bound_seconds(*work(shape, r.config),
-                                    r.device_kind)[0] for shape in r.calls)
+    bound = _least_seconds(r, kernel, "int8_ops")
     lo, hi = r.window
-    seconds = sum(o.end - o.start for o in r.ops
-                  if o.kernel == kernel and lo <= o.start < hi) / 1e9
+    seconds = sum(max(0, min(o.end, hi) - max(o.start, lo)) for o in r.ops
+                  if o.kernel == kernel) / 1e9
     if bound <= 0 or seconds <= 0:
         return None
     return 100.0 * bound / seconds
+
+
+def step_share(r: Reading, work: str, peak: str) -> Optional[float]:
+    """Percent of the window that the chip needs at least for the logical
+    work of every call of the window (``work/<work>.py``): operations at
+    the peak rate ``peak`` of ``peaks.PEAKS``, bytes at HBM bandwidth.
+    ``None`` where the window made no call, or off the chips of the peak
+    table."""
+    lo, hi = r.window
+    if not r.calls or hi <= lo or r.device_kind not in peaks.PEAKS:
+        return None
+    return 100.0 * _least_seconds(r, work, peak) / ((hi - lo) / 1e9)
 
 
 def glue_share(r: Reading) -> Optional[float]:

@@ -161,12 +161,11 @@ def run(args, *, root: str = ROOT, require_tpu: bool = True,
 
     from chipbench import systems
     cfg = cell.config
-    system = systems.build(cfg, backend or cfg["backend"])
+    system = systems.build(cell.system, cfg, backend or cfg["backend"])
     if patch is not None:
         system = patch(system)
     spans = loops.Spans(bool(args.trace))
-    loop = loops.LOOPS[cell.mix["loop"]](system, cell.mix, args.seed, spans,
-                                          cfg["name"])
+    loop = cell.loop(system, cell.mix, args.seed, spans, cfg["name"])
     loop.setup()
     setup_s = time.perf_counter() - t_start
 

@@ -1,9 +1,10 @@
 """A copy of the benchmark at test sizes, for CPU rehearsals.
 
-The copy holds ``chipbench/`` and a ``BENCHMARK.json`` whose mixes are
-shrunk to a few small images or GEMMs, next to a link to the program's
-``src/``; the harness finds every piece by name there, exactly as in a
-checkout.
+The copy holds ``chipbench/`` and a ``BENCHMARK.json`` next to a link
+to the program's ``src/``; each mix there is shrunk to the ``test_size``
+parameters its own file carries (a few small images or GEMMs), which a
+run never reads.  The harness finds every piece by name there, exactly
+as in a checkout.
 """
 
 from __future__ import annotations
@@ -15,13 +16,6 @@ import shutil
 REPO = os.path.dirname(os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))))
 
-#: Test-size parameters of each mix (merged over the mix file).
-TINY = {
-    "stream-b4-1024": {"batch": 2, "size": 40, "pool": 3, "depth": 2,
-                       "chunk": 4, "sample": 3},
-    "gemm-r50-b32": {"shapes": [[24, 200, 16], [16, 128, 8]], "ahead": 2},
-}
-
 
 def bench_copy(tmp_path, workloads=None) -> str:
     """A test-size benchmark checkout under ``tmp_path``; its root."""
@@ -31,13 +25,14 @@ def bench_copy(tmp_path, workloads=None) -> str:
                     ignore=shutil.ignore_patterns("__pycache__"))
     os.symlink(os.path.join(REPO, "src"), os.path.join(root, "src"))
     mixes = os.path.join(root, "chipbench", "mixes")
-    for name, params in TINY.items():
-        path = os.path.join(mixes, name + ".json")
+    for name in os.listdir(mixes):
+        path = os.path.join(mixes, name)
         with open(path) as f:
             mix = json.load(f)
-        mix.update(params)
-        with open(path, "w") as f:
-            json.dump(mix, f)
+        if "test_size" in mix:
+            mix.update(mix.pop("test_size"))
+            with open(path, "w") as f:
+                json.dump(mix, f)
     with open(os.path.join(REPO, "BENCHMARK.json")) as f:
         bench = json.load(f)
     if workloads is not None:

@@ -88,6 +88,44 @@ def test_roofline_counts_logical_work_over_all_kernel_time():
                              "conv_chain") is None
 
 
+@pytest.mark.parametrize("peak,which", [("bf16_flops", "ops"),
+                                         ("int8_ops", "bytes")])
+def test_step_share_counts_every_call_s_work_over_the_window(peak, which):
+    """The least time for each call's work at the named peak, summed over
+    the calls and over the window's length, whatever ran on the device."""
+    tr = trace_lib.Trace(devices={DEV: []},
+                         spans=[("cb.window", 0, 40_000_000)])
+    shape = (1024, 1024, 1024)
+    ops, nbytes = 2 * 1024 ** 3, 6 * 1024 ** 2
+    bound, binds = peaks.bound_seconds(ops, nbytes, "TPU v5 lite", peak)
+    assert binds == which
+    assert bound == pytest.approx(ops / 197e12 if which == "ops"
+                                  else nbytes / 819e9)
+    r = readings.Reading(trace=tr, device=DEV, window=tr.window(),
+                         device_kind="TPU v5 lite", calls=[shape] * 3,
+                         config={}, counters={})
+    assert readings.step_share(r, "mac_matmul", peak) \
+        == pytest.approx(100 * 3 * bound / 0.04)
+    r.calls = []
+    assert readings.step_share(r, "mac_matmul", peak) is None
+    r.calls, r.device_kind = [shape], "cpu"
+    assert readings.step_share(r, "mac_matmul", peak) is None
+
+
+def test_roofline_counts_the_part_of_a_kernel_inside_the_window():
+    """The first call's kernel, put 5 ns before the window by the
+    trace's clocks, still counts; a kernel wholly outside does not."""
+    tr = _trace()
+    tr.devices[DEV] += [trace_lib.Op("conv_chain.0", "conv_chain", -5, 15),
+                        trace_lib.Op("conv_chain.9", "conv_chain", 100, 130)]
+    shape = (4, 1024, 1024)
+    bound = peaks.bound_seconds(6 * 4 * 1024 * 1024, 4 * 4 * 1024 * 1024,
+                                "TPU v5 lite")[0]
+    # 10..30 and 25..40 as before, plus 0..15 of the straddling one
+    assert readings.roofline(_reading(tr, [shape]), "conv_chain") \
+        == pytest.approx(100 * bound / 50e-9)
+
+
 def test_roofline_reads_nothing_without_the_kernel():
     tr = _trace()
     tr.devices[DEV] = [o for o in tr.devices[DEV] if not o.kernel]
