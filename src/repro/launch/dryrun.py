@@ -183,12 +183,12 @@ def run_cell(arch: str, shape: str, mesh_kind: str, approx: str,
             pos = jax.ShapeDtypeStruct((), jnp.int32)
             fn = make_decode_step(cfg, batch_axes=ba)
             jfn = jax.jit(
-                fn, in_shardings=(p_shard, b_shard,
+                fn, in_shardings=(p_shard, b_shard["tokens"],
                                   jax.sharding.NamedSharding(
                                       mesh, jax.sharding.PartitionSpec()),
                                   c_shard),
                 donate_argnums=(3,))
-            lowered = jfn.lower(p_shapes, specs, pos, c_shapes)
+            lowered = jfn.lower(p_shapes, specs["tokens"], pos, c_shapes)
 
         t_lower = time.time()
         compiled = lowered.compile()

@@ -12,6 +12,7 @@ from typing import Any, Dict
 import jax
 import jax.numpy as jnp
 
+from repro import obs
 from repro.models import transformer as T
 from repro.models.config import ModelConfig
 from repro.optim import adamw
@@ -71,25 +72,39 @@ def make_train_step(cfg: ModelConfig, opt_cfg: adamw.AdamWConfig,
     return train_step
 
 
+def _spanned(name: str, fn):
+    """``fn`` called inside the ``repro.obs`` span ``name``."""
+    @functools.wraps(fn)
+    def call(*args):
+        with obs.span(name):
+            return fn(*args)
+
+    return call
+
+
 def make_prefill_step(cfg: ModelConfig, ctx_len: int, batch_axes=None):
+    """``(params, batch) -> (logits, cache, stats)``, jitted, each call in
+    the span ``model:prefill``."""
     def prefill_step(params, batch):
         b = (batch["tokens"] if "tokens" in batch else batch["frames"]).shape[0]
         cache = T.init_cache(cfg, b, ctx_len)
-        logits, cache, _ = T.forward(params, cfg, batch, mode="prefill",
-                                     cache=cache, batch_axes=batch_axes)
-        return logits, cache
+        return T.forward(params, cfg, batch, mode="prefill", cache=cache,
+                         batch_axes=batch_axes)
 
-    return prefill_step
+    return _spanned("model:prefill", jax.jit(prefill_step))
 
 
-def make_decode_step(cfg: ModelConfig, batch_axes=None):
-    def decode_step(params, batch, pos, cache):
-        logits, cache, _ = T.forward(params, cfg, batch, mode="decode",
-                                     cache=cache, pos=pos,
-                                     batch_axes=batch_axes)
-        return logits, cache
+def make_decode_step(cfg: ModelConfig, batch_axes=None, taps=False):
+    """``(params, tokens (B, 1), pos () or (B,), cache) -> (logits, cache,
+    stats)``, jitted with the cache donated (updated in place), each call
+    in the span ``model:decode``.  ``taps``: stats also holds the blocks'
+    intermediates (``transformer.forward``)."""
+    def decode_step(params, tokens, pos, cache):
+        return T.forward(params, cfg, {"tokens": tokens}, mode="decode",
+                         cache=cache, pos=pos, batch_axes=batch_axes,
+                         taps=taps)
 
-    return decode_step
+    return _spanned("model:decode", jax.jit(decode_step, donate_argnums=3))
 
 
 def state_shapes(cfg: ModelConfig, opt_cfg: adamw.AdamWConfig, seed=0):

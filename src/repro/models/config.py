@@ -55,7 +55,22 @@ class MoEConfig:
     d_ff: int
     num_shared_experts: int = 0
     shared_d_ff: int = 0
-    capacity_factor: float = 1.25
+    # Tokens past capacity_factor * fair share of an expert are dropped;
+    # None drops none.
+    capacity_factor: Optional[float] = 1.25
+    # Routing.  "greedy": top-k of the softmax over all experts.
+    # "group_limited_greedy" (DeepSeek-V2): experts form n_group groups,
+    # a group scores its best expert, and the top-k is taken inside the
+    # topk_group best groups.  norm_topk_prob renormalises the k gates;
+    # otherwise they are scaled by routed_scaling_factor.
+    topk_method: str = "greedy"
+    n_group: int = 1
+    topk_group: int = 1
+    routed_scaling_factor: float = 1.0
+    norm_topk_prob: bool = True
+    # The experts this chip holds: (first, count).  None holds all.  The
+    # router still scores every expert; only held experts are computed.
+    held: Optional[Tuple[int, int]] = None
     # Sequence is processed in this many sequential chunks inside the MoE
     # layer to bound the (B, E, C, D) dispatch buffers (memory knob).
     seq_chunks: int = 1
@@ -69,6 +84,28 @@ class MoEConfig:
     # EXPERIMENTS.md §Perf; hence per-arch.
     dispatch_pin: bool = True
 
+    def __post_init__(self):
+        assert self.topk_method in ("greedy", "group_limited_greedy"), \
+            self.topk_method
+        assert self.num_experts % self.n_group == 0
+        first, count = self.held_range
+        assert 0 <= first and first + count <= self.num_experts
+
+    @property
+    def held_range(self) -> Tuple[int, int]:
+        return self.held if self.held is not None else (0, self.num_experts)
+
+
+@dataclasses.dataclass(frozen=True)
+class YarnConfig:
+    """YaRN RoPE scaling (DeepseekV2YarnRotaryEmbedding)."""
+    factor: float = 40.0
+    original_max_position: int = 4096
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    mscale: float = 0.707
+    mscale_all_dim: float = 0.707
+
 
 @dataclasses.dataclass(frozen=True)
 class MLAConfig:
@@ -80,6 +117,7 @@ class MLAConfig:
     # Decode path: "decompress" (naive baseline) or "absorbed" (latent-space
     # attention; the optimized variant — see EXPERIMENTS.md §Perf).
     decode_mode: str = "decompress"
+    yarn: Optional[YarnConfig] = None
 
 
 @dataclasses.dataclass(frozen=True)

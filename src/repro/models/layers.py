@@ -19,6 +19,7 @@ Conventions
 from __future__ import annotations
 
 import functools
+import math
 
 import jax
 import jax.numpy as jnp
@@ -60,11 +61,45 @@ def rms_norm(p, x, eps: float = 1e-6):
 
 # ------------------------------------------------------------------- RoPE
 
-def rope_tables(positions: Array, dim: int, base: float):
-    """cos/sin tables for `positions` (any leading shape) -> (..., dim/2)."""
-    inv_freq = 1.0 / (base ** (np.arange(0, dim, 2, dtype=np.float32) / dim))
+def yarn_mscale(scale: float, mscale: float = 1.0) -> float:
+    return 1.0 if scale <= 1 else 0.1 * mscale * math.log(scale) + 1.0
+
+
+def _yarn_inv_freq(dim: int, base: float, yarn) -> np.ndarray:
+    """DeepseekV2YarnRotaryEmbedding's frequencies: the interpolated
+    ``freq / factor`` and the original ``freq`` blended by a linear ramp
+    between the correction dimensions of ``beta_fast`` and ``beta_slow``
+    rotations over the original context."""
+    def corr_dim(rotations):
+        return (dim * math.log(yarn.original_max_position
+                               / (rotations * 2 * math.pi))
+                / (2 * math.log(base)))
+
+    low = max(math.floor(corr_dim(yarn.beta_fast)), 0)
+    high = min(math.ceil(corr_dim(yarn.beta_slow)), dim - 1)
+    if low == high:
+        high += 0.001
+    extra = 1.0 / (base ** (np.arange(0, dim, 2, dtype=np.float32) / dim))
+    ramp = np.clip((np.arange(dim // 2, dtype=np.float32) - low)
+                   / (high - low), 0, 1)
+    keep = 1.0 - ramp
+    return (extra / yarn.factor) * (1 - keep) + extra * keep
+
+
+def rope_tables(positions: Array, dim: int, base: float, yarn=None):
+    """cos/sin tables for `positions` (any leading shape) -> (..., dim/2).
+    With ``yarn`` (a :class:`~repro.models.config.YarnConfig`) the
+    frequencies and the table scale are YaRN's."""
+    if yarn is None:
+        inv_freq = 1.0 / (base ** (np.arange(0, dim, 2, dtype=np.float32)
+                                   / dim))
+        scale = 1.0
+    else:
+        inv_freq = _yarn_inv_freq(dim, base, yarn)
+        scale = (yarn_mscale(yarn.factor, yarn.mscale)
+                 / yarn_mscale(yarn.factor, yarn.mscale_all_dim))
     ang = positions.astype(jnp.float32)[..., None] * inv_freq
-    return jnp.cos(ang), jnp.sin(ang)
+    return jnp.cos(ang) * scale, jnp.sin(ang) * scale
 
 
 def apply_rope(x: Array, cos: Array, sin: Array):

@@ -9,6 +9,17 @@ Two decode paths (cfg.mla.decode_mode):
   "absorbed"   — fold W^UK into the query and W^UV into the output and
                  attend directly in latent space (FLOPs ~ S * H * kvlr).
 The absorbed path is the §Perf-optimized variant; both are tested equal.
+
+RoPE may be YaRN-scaled (``cfg.mla.yarn``, DeepSeek-V2's published
+setting): YaRN's frequencies, and the softmax scale multiplied by
+``mscale(factor, mscale_all_dim)**2``.  The published code de-interleaves
+the rope dimensions before rotating them; here the halves are rotated as
+they lie, which for seeded weights is a fixed relabelling of the rope
+columns of ``wq_b`` and ``wkv_a``.
+
+Decode takes one position per batch row (a scalar broadcasts): row b
+writes its latent at slot ``pos[b]`` and attends to slots ``<= pos[b]``,
+so slots past a row's length (padding, an earlier answer) are never read.
 """
 
 from __future__ import annotations
@@ -46,7 +57,8 @@ def _queries(p, cfg, x, positions, spec):
     q = L.dense(p["wq_b"], cq).reshape(
         b, s, h, m.nope_head_dim + m.rope_head_dim)
     q_nope, q_rope = jnp.split(q, [m.nope_head_dim], axis=-1)
-    cos, sin = L.rope_tables(positions, m.rope_head_dim, spec.rope_base)
+    cos, sin = L.rope_tables(positions, m.rope_head_dim, spec.rope_base,
+                             m.yarn)
     q_rope = L.apply_rope(q_rope, cos, sin)
     return q_nope, q_rope
 
@@ -56,9 +68,17 @@ def _latents(p, cfg, x, positions, spec):
     ckv_kr = L.dense(p["wkv_a"], x)
     c_kv, k_rope = jnp.split(ckv_kr, [m.kv_lora_rank], axis=-1)
     c_kv = L.rms_norm(p["kv_ln"], c_kv, cfg.norm_eps)
-    cos, sin = L.rope_tables(positions, m.rope_head_dim, spec.rope_base)
+    cos, sin = L.rope_tables(positions, m.rope_head_dim, spec.rope_base,
+                             m.yarn)
     k_rope = L.apply_rope(k_rope[:, :, None, :], cos, sin)[:, :, 0, :]
     return c_kv, k_rope
+
+
+def _yarn_factor(m) -> float:
+    """YaRN's factor on the softmax scale (1 without YaRN)."""
+    if m.yarn is None or not m.yarn.mscale_all_dim:
+        return 1.0
+    return L.yarn_mscale(m.yarn.factor, m.yarn.mscale_all_dim) ** 2
 
 
 def _expand_kv(p, cfg, c_kv):
@@ -80,6 +100,9 @@ def _full_attention(p, cfg, spec, q_nope, q_rope, c_kv, k_rope, positions,
                                   (*k_nope.shape[:3], m.rope_head_dim))],
         axis=-1)
     q = jnp.concatenate([q_nope, q_rope], axis=-1)
+    factor = _yarn_factor(m)
+    if factor != 1.0:
+        q = (q.astype(jnp.float32) * factor).astype(q.dtype)
     out = L.attention_any(q, k, v, positions, kvpos, causal=True,
                           window=spec.window, kv_chunk=cfg.attn_kv_chunk)
     return L.dense(p["wo"], out.reshape(b, s, cfg.num_heads * m.v_head_dim))
@@ -98,7 +121,6 @@ def mla_cache_init(cfg: ModelConfig, batch: int, ctx_len: int,
     return {
         "ckv": jnp.zeros((batch, ctx_len, m.kv_lora_rank), dtype),
         "krope": jnp.zeros((batch, ctx_len, m.rope_head_dim), dtype),
-        "pos": jnp.full((ctx_len,), -1, jnp.int32),
     }
 
 
@@ -112,33 +134,42 @@ def mla_prefill(p, cfg, spec, x, positions, cache):
         "ckv": cache["ckv"].at[:, :s].set(c_kv.astype(cache["ckv"].dtype)),
         "krope": cache["krope"].at[:, :s].set(
             k_rope.astype(cache["krope"].dtype)),
-        "pos": cache["pos"].at[:s].set(positions),
     }
     return out, cache
 
 
-def mla_decode(p, cfg: ModelConfig, spec: BlockSpec, x, pos, cache):
+def mla_decode(p, cfg: ModelConfig, spec: BlockSpec, x, pos, cache,
+               layer=None):
+    """One token per row at positions ``pos`` (shape () or (B,)).  With
+    ``layer``, ``cache`` is a stack of layers' caches and this layer's
+    is at that index: the new row is written into the stack in place."""
+    with jax.named_scope("mla:decode"):
+        return _decode(p, cfg, spec, x, pos, cache, layer)
+
+
+def _decode(p, cfg, spec, x, pos, cache, layer):
     m = cfg.mla
     b = x.shape[0]
     h = cfg.num_heads
-    positions = pos[None]
+    pos = jnp.broadcast_to(jnp.asarray(pos, jnp.int32), (b,))
+    positions = pos[:, None]                  # (B, 1)
     q_nope, q_rope = _queries(p, cfg, x, positions, spec)
     c_kv_t, k_rope_t = _latents(p, cfg, x, positions, spec)
-    slot = positions[0]
+    at = (jnp.arange(b), pos) if layer is None else (layer, jnp.arange(b),
+                                                       pos)
     cache = {
-        "ckv": jax.lax.dynamic_update_slice_in_dim(
-            cache["ckv"], c_kv_t.astype(cache["ckv"].dtype), slot, axis=1),
-        "krope": jax.lax.dynamic_update_slice_in_dim(
-            cache["krope"], k_rope_t.astype(cache["krope"].dtype), slot,
-            axis=1),
-        "pos": jax.lax.dynamic_update_slice_in_dim(
-            cache["pos"], positions.astype(jnp.int32), slot, axis=0),
+        "ckv": cache["ckv"].at[at].set(
+            c_kv_t[:, 0].astype(cache["ckv"].dtype)),
+        "krope": cache["krope"].at[at].set(
+            k_rope_t[:, 0].astype(cache["krope"].dtype)),
     }
-    kvpos = cache["pos"]
-    if cfg.mla.decode_mode == "decompress":
-        out = _full_attention(p, cfg, spec, q_nope, q_rope,
-                              cache["ckv"].astype(x.dtype),
-                              cache["krope"].astype(x.dtype),
+    ckv, krope = ((cache["ckv"], cache["krope"]) if layer is None else
+                  (cache["ckv"][layer], cache["krope"][layer]))
+    ckv = ckv.astype(x.dtype)                 # (B,S,r)
+    krope = krope.astype(x.dtype)             # (B,S,dr)
+    kvpos = jnp.arange(ckv.shape[1], dtype=jnp.int32)
+    if m.decode_mode == "decompress":
+        out = _full_attention(p, cfg, spec, q_nope, q_rope, ckv, krope,
                               positions, kvpos)
         return out, cache
 
@@ -149,14 +180,12 @@ def mla_decode(p, cfg: ModelConfig, spec: BlockSpec, x, pos, cache):
     w_uv = wkv_b[..., m.nope_head_dim:]       # (r, H, dv)
     # q_lat[b,1,h,r] = q_nope . W^UK
     q_lat = jnp.einsum("bqhn,rhn->bqhr", q_nope, w_uk)
-    ckv = cache["ckv"].astype(x.dtype)        # (B,S,r)
-    krope = cache["krope"].astype(x.dtype)    # (B,S,dr)
-    scale = (m.nope_head_dim + m.rope_head_dim) ** -0.5
+    scale = (m.nope_head_dim + m.rope_head_dim) ** -0.5 * _yarn_factor(m)
     s_lat = jnp.einsum("bqhr,bkr->bhqk", q_lat, ckv)
     s_rope = jnp.einsum("bqhd,bkd->bhqk", q_rope, krope)
     scores = (s_lat + s_rope).astype(jnp.float32) * scale
     bias = L._mask_bias(positions, kvpos, causal=True, window=spec.window)
-    probs = jax.nn.softmax(scores + bias[None, None], axis=-1)
+    probs = jax.nn.softmax(scores + bias[:, None], axis=-1)
     ctx_lat = jnp.einsum("bhqk,bkr->bqhr", probs.astype(x.dtype), ckv)
     out = jnp.einsum("bqhr,rhv->bqhv", ctx_lat, w_uv)
     out = L.dense(p["wo"], out.reshape(b, 1, h * m.v_head_dim))

@@ -1,18 +1,30 @@
-"""Mixture-of-Experts MLP (token-choice top-k, capacity-based, dropping).
+"""Mixture-of-Experts MLP: one router over every expert, and the part of
+the result that the experts held here give.
 
-Dispatch is sort-based and gather-formulated (no (T,E,C) one-hot einsum):
-per batch row, tokens' (token, k-slot) pairs are ranked within their expert
-queue; the first C per expert are gathered into a dense (B, E, C, D)
-buffer.  Expert FFNs run as stacked einsums with E sharded over the
-"model"/expert-parallel mesh axis; GSPMD materializes the token
-redistribution as all-to-all/all-gather collectives (measured in §Roofline).
+Routing (:func:`route`) is the softmax over all ``num_experts`` router
+outputs, then either a plain top-k (``"greedy"``) or DeepSeek-V2's
+``"group_limited_greedy"`` (the top-k inside the ``topk_group`` groups
+whose best expert scores highest); the k gates are renormalised
+(``norm_topk_prob``) or scaled by ``routed_scaling_factor``.
 
-Memory knob: the sequence is processed in `seq_chunks` sequential chunks
-(lax.scan), bounding the dispatch buffers for very wide expert counts
-(DeepSeek-V2: 160 experts).
+A layer holds the experts ``held = (first, count)`` (all by default): its
+parameter stacks are ``(count, D, F)``, and it computes only those
+experts, for the (token, slot) pairs routed to them.  On one chip that
+is a share of an expert-parallel deployment; under ``shard_map`` each
+rank is the share ``first = rank * count`` and one ``psum`` adds the
+shares.  Shared experts are added by every share alike.
 
-Decode (S == 1) merges the batch into a single dispatch group so expert
-capacity stays ~B*k/E instead of forcing one slot per (row, expert).
+The held experts run over a sort-based dispatch: per batch row the
+(token, slot) pairs are ranked within their expert's queue and the
+first C of each held expert are gathered into a (B, count, C, D) buffer;
+pairs past C are dropped.  ``capacity_factor`` None makes C the chunk's
+token count, which no queue can pass (a token picks an expert at most
+once), so nothing is dropped.
+
+Memory knob: the sequence is processed in ``seq_chunks`` sequential
+chunks (lax.scan), bounding the dispatch buffers.  Decode (S == 1)
+merges the batch into a single dispatch group so expert capacity stays
+~B*k/E instead of forcing one slot per (row, expert).
 """
 
 from __future__ import annotations
@@ -35,13 +47,14 @@ def _pin(x, batch_axes, *rest):
 def moe_init(key, cfg: ModelConfig):
     mc = cfg.moe
     ks = jax.random.split(key, 5)
-    e, d, f = mc.num_experts, cfg.d_model, mc.d_ff
+    _, e = mc.held_range
+    d, f = cfg.d_model, mc.d_ff
 
     def stack(k, shape, fan_in):
         return jax.random.normal(k, shape, jnp.float32) * fan_in ** -0.5
 
     p = {
-        "router": L.dense_init(ks[0], d, e),
+        "router": L.dense_init(ks[0], d, mc.num_experts),
         "wi": stack(ks[1], (e, d, f), d),
         "wg": stack(ks[2], (e, d, f), d),
         "wo": stack(ks[3], (e, f, d), f),
@@ -52,15 +65,37 @@ def moe_init(key, cfg: ModelConfig):
     return p
 
 
+def route(logits, mc):
+    """Router logits (..., E) -> (gates (..., k) f32, ids (..., k),
+    probs (..., E) f32)."""
+    probs = jax.nn.softmax(logits.astype(jnp.float32), axis=-1)
+    scores = probs
+    if mc.topk_method == "group_limited_greedy":
+        g = mc.n_group
+        grouped = probs.reshape(*probs.shape[:-1], g, mc.num_experts // g)
+        _, top = jax.lax.top_k(grouped.max(axis=-1), mc.topk_group)
+        keep = jnp.any(top[..., :, None] == jnp.arange(g), axis=-2)
+        scores = jnp.where(keep[..., None], grouped, 0.0).reshape(
+            probs.shape)
+    gates, ids = jax.lax.top_k(scores, mc.experts_per_token)
+    if mc.norm_topk_prob:
+        gates = gates / jnp.maximum(gates.sum(-1, keepdims=True), 1e-9)
+    else:
+        gates = gates * mc.routed_scaling_factor
+    return gates, ids, probs
+
+
 def _capacity(tokens: int, mc) -> int:
+    if mc.capacity_factor is None:
+        return tokens
     c = int(tokens * mc.experts_per_token * mc.capacity_factor
             / mc.num_experts)
     return max(4, -(-c // 4) * 4)  # >=4, multiple of 4
 
 
-def _dispatch_indices(ids, gates, num_experts: int, capacity: int):
-    """ids/gates: (B, T, k). Returns (src (B,E,C) token index or T=invalid,
-    combine info (dest slot per (B,T,k), keep mask))."""
+def _dispatch_indices(ids, num_experts: int, capacity: int):
+    """ids: (B, T, k). Returns (src (B,E,C) token index or T=invalid,
+    dest slot per (B,T,k), keep mask)."""
     b, t, k = ids.shape
     flat = ids.reshape(b, t * k)
     order = jnp.argsort(flat, axis=-1, stable=True)          # (B, Tk)
@@ -82,7 +117,7 @@ def _dispatch_indices(ids, gates, num_experts: int, capacity: int):
         jnp.arange(t * k, dtype=jnp.int32)[None, :], mode="drop")
     src = src.reshape(b, num_experts, capacity + 1)[:, :, :capacity]
     src_tok = jnp.minimum(src // k, t)                        # token index
-    return src_tok, (src, dest, keep)
+    return src_tok, dest, keep
 
 
 def _expert_ffn(p, xin):
@@ -92,26 +127,23 @@ def _expert_ffn(p, xin):
     return jnp.einsum("becf,efd->becd", h, p["wo"].astype(xin.dtype))
 
 
-def _moe_chunk(p, cfg: ModelConfig, x, batch_axes=None):
-    """x: (B, T, D) one sequence chunk."""
-    mc = cfg.moe
+def _dispatch(p, mc, x, gates, ids, first, count, batch_axes):
+    """Held experts over their first C pairs each (capacity dispatch);
+    the weighted sum over a token's slots is float32."""
     b, t, d = x.shape
-    logits = L.dense(p["router"], x).astype(jnp.float32)      # (B,T,E)
-    probs = jax.nn.softmax(logits, axis=-1)
-    gates, ids = jax.lax.top_k(probs, mc.experts_per_token)   # (B,T,k)
-    gates = gates / jnp.maximum(gates.sum(-1, keepdims=True), 1e-9)
     cap = _capacity(t, mc)
-    src_tok, (src, dest, keep) = _dispatch_indices(
-        ids, gates, mc.num_experts, cap)
+    src_tok, dest, keep = _dispatch_indices(ids, mc.num_experts, cap)
+    if count != mc.num_experts:
+        src_tok = jax.lax.dynamic_slice_in_dim(src_tok, first, count, 1)
     xpad = jnp.concatenate([x, jnp.zeros((b, 1, d), x.dtype)], axis=1)
-    xin = xpad[jnp.arange(b)[:, None, None], src_tok]         # (B,E,C,D)
+    xin = xpad[jnp.arange(b)[:, None, None], src_tok]         # (B,e,C,D)
     # Keep the dispatch gather LOCAL to the batch shard (E replicated);
     # the expert einsum then slices its E shard for free.  Without the
     # pin GSPMD partial-gathers across batch shards and all-reduces the
     # full (B,E,C,D) buffer (measured: 2.7 GB x layers, §Perf granite).
     if mc.dispatch_pin:
         xin = _pin(xin, batch_axes, None, None, None)
-    yout = _expert_ffn(p, xin)                                # (B,E,C,D)
+    yout = _expert_ffn(p, xin)                                # (B,e,C,D)
     if mc.dispatch_pin:
         yout = _pin(yout, batch_axes, None, None, None)
     # Combine: gather each (token, k) slot's result and weight by its gate.
@@ -119,45 +151,77 @@ def _moe_chunk(p, cfg: ModelConfig, x, batch_axes=None):
     # let GSPMD emit partial sums + one small all-reduce; MEASURED WORSE —
     # GSPMD all-gathers both scatter operands, 2.5x the collective bytes.
     # Hypothesis refuted; see EXPERIMENTS.md §Perf granite iteration 3.)
-    ybuf = yout.reshape(b, mc.num_experts * cap, d)
-    lin = ids.reshape(b, -1) * cap + jnp.minimum(dest, cap - 1)
+    ybuf = yout.reshape(b, count * cap, d)
+    flat = ids.reshape(b, -1) - first
+    held = (flat >= 0) & (flat < count)
+    lin = jnp.clip(flat * cap + jnp.minimum(dest, cap - 1), 0,
+                   count * cap - 1)
     gathered = jnp.take_along_axis(
         ybuf, lin[:, :, None].astype(jnp.int32), axis=1)      # (B,Tk,D)
-    w = (gates.reshape(b, -1) * keep.astype(gates.dtype))[:, :, None]
-    out = (gathered.astype(jnp.float32) * w).reshape(
-        b, t, mc.experts_per_token, d).sum(axis=2).astype(x.dtype)
+    w = (gates.reshape(b, -1) * (keep & held).astype(gates.dtype))
+    return (gathered.astype(jnp.float32) * w[:, :, None]).reshape(
+        b, t, mc.experts_per_token, d).sum(axis=2)
+
+
+def _chunk(p, cfg: ModelConfig, x, first, count, batch_axes=None):
+    """x: (B, T, D) one sequence chunk -> (held part, aux loss, held
+    (token, slot) pairs, gates (B, T, k), ids (B, T, k))."""
+    mc = cfg.moe
+    b, t, _ = x.shape
+    gates, ids, probs = route(L.dense(p["router"], x), mc)
+    out = _dispatch(p, mc, x, gates, ids, first, count, batch_axes)
+    pairs = jnp.sum((ids >= first) & (ids < first + count), dtype=jnp.int32)
     # router load-balancing auxiliary loss (Switch-style), returned for logs
     me = probs.mean(axis=(0, 1))
     ce = jnp.zeros_like(me).at[ids.reshape(-1)].add(
         jnp.ones((b * t * mc.experts_per_token,), jnp.float32)
     ) / (b * t * mc.experts_per_token)
     aux = mc.num_experts * jnp.sum(me * ce)
-    return out, aux
+    return out, aux, pairs, gates, ids
 
 
-def moe_apply(p, cfg: ModelConfig, x, batch_axes=None):
-    """x: (B, S, D) -> (out (B,S,D), aux_loss scalar)."""
+def _held_part(p, cfg: ModelConfig, x, first, count, batch_axes=None):
+    """x: (B, S, D) -> (the held experts' part (B,S,D), aux, pairs,
+    gates (B,S,k), ids (B,S,k))."""
     mc = cfg.moe
     b, s, d = x.shape
     if s == 1:
-        out, aux = _moe_chunk(p, cfg, x.reshape(1, b, d))
-        out = out.reshape(b, 1, d)
-    elif mc.seq_chunks > 1 and s % mc.seq_chunks == 0:
+        out, aux, pairs, gates, ids = _chunk(p, cfg, x.reshape(1, b, d),
+                                             first, count)
+        k = mc.experts_per_token
+        return (out.reshape(b, 1, d), aux, pairs, gates.reshape(b, 1, k),
+                ids.reshape(b, 1, k))
+    if mc.seq_chunks > 1 and s % mc.seq_chunks == 0:
         t = s // mc.seq_chunks
         xs = x.reshape(b, mc.seq_chunks, t, d).transpose(1, 0, 2, 3)
 
         def body(_, xc):
-            o, a = _moe_chunk(p, cfg, xc, batch_axes)
-            return None, (o, a)
+            return None, _chunk(p, cfg, xc, first, count, batch_axes)
 
-        _, (outs, auxs) = jax.lax.scan(body, None, xs)
-        out = outs.transpose(1, 0, 2, 3).reshape(b, s, d)
-        aux = auxs.mean()
-    else:
-        out, aux = _moe_chunk(p, cfg, x, batch_axes)
-    if "shared" in p:
-        out = out + L.swiglu(p["shared"], x)
-    return out, aux
+        _, (outs, auxs, pairs, gates, ids) = jax.lax.scan(body, None, xs)
+
+        def unchunk(a):
+            return a.transpose(1, 0, 2, 3).reshape(b, s, a.shape[-1])
+        return (unchunk(outs), auxs.mean(), pairs.sum(), unchunk(gates),
+                unchunk(ids))
+    return _chunk(p, cfg, x, first, count, batch_axes)
+
+
+def _stats(aux, pairs, gates, ids):
+    return {"aux": aux, "held_pairs": pairs, "gates": gates, "ids": ids}
+
+
+def moe_apply(p, cfg: ModelConfig, x, batch_axes=None):
+    """x: (B, S, D) -> (out (B,S,D) float32, stats): the held experts'
+    part plus the shared experts; stats holds the router's ``aux`` loss, the
+    ``held_pairs`` and each token's ``gates`` and expert ``ids``
+    (B, S, k)."""
+    first, count = cfg.moe.held_range
+    with jax.named_scope("moe:experts"):
+        out, *stats = _held_part(p, cfg, x, first, count, batch_axes)
+        if "shared" in p:
+            out = out + L.swiglu(p["shared"], x)
+    return out, _stats(*stats)
 
 
 # ----------------------------------------------------- shard_map dispatch --
@@ -166,11 +230,11 @@ def moe_apply_shard_map(p, cfg: ModelConfig, x, batch_axes=None, mesh=None):
     """Manual expert-parallel dispatch via shard_map (beyond-GSPMD path).
 
     Observation (EXPERIMENTS.md §Perf): activations are replicated across
-    the "model" axis, so every model rank can gather ITS OWN experts'
-    (B, E_local, C, D) buffer with ZERO communication, run its expert FFNs
-    locally, combine partially (masking other ranks' gates), and finish
-    with ONE psum of the (B, T, D) output over "model" — instead of
-    GSPMD's all-reduce/all-gather of full dispatch buffers.
+    the "model" axis, so every model rank computes ITS OWN experts' part
+    (the held share ``first = rank * count``) with ZERO communication
+    and the shares meet in ONE psum of the (B, T, D) output over
+    "model" — instead of GSPMD's all-reduce/all-gather of full dispatch
+    buffers.
 
     Falls back to moe_apply when no mesh/model axis is available, at
     decode (S == 1), or when num_experts % model_size != 0.
@@ -187,8 +251,7 @@ def moe_apply_shard_map(p, cfg: ModelConfig, x, batch_axes=None, mesh=None):
             or s == 1 or mc.num_experts % mesh.shape["model"] != 0):
         return moe_apply(p, cfg, x, batch_axes)
 
-    e_local = mc.num_experts // mesh.shape["model"]
-    dtype = x.dtype
+    count = mc.num_experts // mesh.shape["model"]
     bspec = P(batch_axes, None, None)
     espec = P("model", None, None)
     rspec = P(None, None)
@@ -197,61 +260,20 @@ def moe_apply_shard_map(p, cfg: ModelConfig, x, batch_axes=None, mesh=None):
               in_specs=(bspec, rspec, espec, espec, espec),
               out_specs=bspec)
     def run(xl, router, wg, wi, wo):
-        bl, sl, _ = xl.shape
-        chunks = mc.seq_chunks if sl % max(1, mc.seq_chunks) == 0 else 1
-        t = sl // chunks
-        rank = jax.lax.axis_index("model")
-        lo = rank * e_local
-
-        def one_chunk(carry, xc):
-            logits = (xc @ router.astype(xc.dtype)).astype(jnp.float32)
-            probs = jax.nn.softmax(logits, axis=-1)
-            gates, ids = jax.lax.top_k(probs, mc.experts_per_token)
-            gates = gates / jnp.maximum(gates.sum(-1, keepdims=True), 1e-9)
-            cap = _capacity(t, mc)
-            src_tok, (src, dest, keep) = _dispatch_indices(
-                ids, gates, mc.num_experts, cap)
-            # slice THIS rank's experts; all index math stays local
-            src_loc = jax.lax.dynamic_slice_in_dim(src_tok, lo, e_local, 1)
-            xpad = jnp.concatenate(
-                [xc, jnp.zeros((bl, 1, d), xc.dtype)], axis=1)
-            xin = xpad[jnp.arange(bl)[:, None, None], src_loc]
-            h = jax.nn.silu(jnp.einsum("becd,edf->becf", xin,
-                                       wg.astype(xin.dtype)))
-            h = h * jnp.einsum("becd,edf->becf", xin, wi.astype(xin.dtype))
-            y = jnp.einsum("becf,efd->becd", h, wo.astype(xin.dtype))
-            # partial combine: only (token, k) slots routed to LOCAL experts
-            ybuf = y.reshape(bl, e_local * cap, d)
-            flat_ids = ids.reshape(bl, -1)
-            is_local = (flat_ids // e_local) == rank
-            lin = (flat_ids - lo) * cap + jnp.minimum(dest, cap - 1)
-            lin = jnp.clip(lin, 0, e_local * cap - 1)
-            gathered = jnp.take_along_axis(
-                ybuf, lin[:, :, None].astype(jnp.int32), axis=1)
-            w = (gates.reshape(bl, -1) * keep.astype(gates.dtype)
-                 * is_local.astype(gates.dtype))[:, :, None]
-            part = (gathered.astype(jnp.float32) * w).reshape(
-                bl, t, mc.experts_per_token, d).sum(axis=2)
-            return carry, part.astype(dtype)
-
-        if chunks > 1:
-            xs = xl.reshape(bl, chunks, t, d).transpose(1, 0, 2, 3)
-            _, parts = jax.lax.scan(one_chunk, None, xs)
-            part = parts.transpose(1, 0, 2, 3).reshape(bl, sl, d)
-        else:
-            _, part = one_chunk(None, xl)
+        first = jax.lax.axis_index("model") * count
+        local = {"router": {"w": router}, "wg": wg, "wi": wi, "wo": wo}
+        part = _held_part(local, cfg, xl, first, count)[0]
         return jax.lax.psum(part, "model")          # THE one collective
 
-    out = run(x, p["router"]["w"], p["wg"], p["wi"], p["wo"])
-    # router aux loss (cheap global recompute, for logging parity)
-    logits = L.dense(p["router"], x).astype(jnp.float32)
-    probs = jax.nn.softmax(logits, axis=-1)
-    _, ids = jax.lax.top_k(probs, mc.experts_per_token)
-    me = probs.mean(axis=(0, 1))
-    ce = jnp.zeros_like(me).at[ids.reshape(-1)].add(
-        jnp.ones((b * s * mc.experts_per_token,), jnp.float32)
-    ) / (b * s * mc.experts_per_token)
-    aux = mc.num_experts * jnp.sum(me * ce)
-    if "shared" in p:
-        out = out + L.swiglu(p["shared"], x)
-    return out, aux
+    with jax.named_scope("moe:experts"):
+        out = run(x, p["router"]["w"], p["wg"], p["wi"], p["wo"])
+        # router aux loss (cheap global recompute, for logging parity)
+        gates, ids, probs = route(L.dense(p["router"], x), mc)
+        me = probs.mean(axis=(0, 1))
+        ce = jnp.zeros_like(me).at[ids.reshape(-1)].add(
+            jnp.ones((b * s * mc.experts_per_token,), jnp.float32)
+        ) / (b * s * mc.experts_per_token)
+        aux = mc.num_experts * jnp.sum(me * ce)
+        if "shared" in p:
+            out = out + L.swiglu(p["shared"], x)
+    return out, _stats(aux, jnp.int32(ids.size), gates, ids)

@@ -23,9 +23,9 @@ def generate(params, cfg: ModelConfig, batch: Dict, max_new_tokens: int,
     tokens = batch["tokens"]
     b, s = tokens.shape
     ctx = ctx_budget or (s + max_new_tokens)
-    prefill = jax.jit(make_prefill_step(cfg, ctx))
-    decode = jax.jit(make_decode_step(cfg))
-    logits, cache = prefill(params, batch)
+    prefill = make_prefill_step(cfg, ctx)
+    decode = make_decode_step(cfg)
+    logits, cache, _ = prefill(params, batch)
     out = [tokens]
     rng = jax.random.key(seed)
     last = None
@@ -41,8 +41,7 @@ def generate(params, cfg: ModelConfig, batch: Dict, max_new_tokens: int,
         out.append(nxt)
         if i == max_new_tokens - 1:
             break
-        logits, cache = decode(params, {"tokens": nxt},
-                               jnp.int32(s + i), cache)
+        logits, cache, _ = decode(params, nxt, jnp.int32(s + i), cache)
     return jnp.concatenate(out, axis=1)
 
 

@@ -11,7 +11,14 @@ whose leaves carry a leading `repeats` axis, consumed by `lax.scan`.
 The paper's technique enters through `cfg.approx`: when enabled, both
 residual-stream adds of every block run through the configured approximate
 adder in fixed point (cfg.approx.residual_add -> repro.ax engine, STE
-gradients).
+gradients).  The residual stream is then carried in float32, which holds
+every value of the fixed-point format exactly (bf16 would round away the
+low bits the adder works on); norms and matmuls still take bf16 inputs.
+
+Decode carries the pattern's stacked cache through the layer scan and
+hands each block its layer index.  A mixer in ``IN_PLACE_DECODE`` writes
+its new row into the (donated) stacked buffer in place and reads its
+layer where it lies; the others slice their layer out and set it back.
 """
 
 from __future__ import annotations
@@ -34,6 +41,17 @@ from repro.models.config import (
 )
 
 Params = Dict[str, Any]
+
+#: Dtype of matmul and norm inputs.
+COMPUTE_DTYPE = jnp.bfloat16
+
+#: Mixers whose decode takes the pattern's stacked cache and the layer
+#: index.  Slicing a layer out and setting it back copies the layer: at
+#: the DeepSeek-V2 cell's size (1.2 GB of latent cache a layer) the
+#: decode step's temporaries grow from 0.3 GB to 1.9 GB
+#: (tests/test_tpu_compile.py).  The other mixers' decode states are
+#: small, or their decode is not on a measured path.
+IN_PLACE_DECODE = frozenset({MLA})
 
 
 # ------------------------------------------------------------------ init --
@@ -64,31 +82,60 @@ def block_init(key, cfg: ModelConfig, spec: BlockSpec) -> Params:
     return p
 
 
-def init_params(rng, cfg: ModelConfig) -> Params:
+def _cast(tree, dtype):
+    return jax.tree.map(lambda a: a.astype(dtype), tree)
+
+
+def init_params(rng, cfg: ModelConfig, dtype=None) -> Params:
+    """Seeded parameters, float32.  With ``dtype`` the same values are
+    cast to it, each block (and each layer of the pattern) built and
+    cast under a jit of its own, so that at most one layer's float32
+    leaves exist at a time."""
     cfg.validate()
+    if dtype is None:
+        def make(fn, *args):
+            return fn(*args)
+    else:
+        jitted = {}
+
+        def make(fn, *args):
+            if fn not in jitted:
+                jitted[fn] = jax.jit(lambda *a: _cast(fn(*a), dtype))
+            return jitted[fn](*args)
+
     keys = jax.random.split(rng, 8)
     p: Params = {}
     d = cfg.d_model
     if cfg.audio is not None:
-        p["frontend"] = L.dense_init(keys[0], cfg.audio.feat_dim, d, bias=True)
+        p["frontend"] = make(functools.partial(
+            L.dense_init, d_in=cfg.audio.feat_dim, d_out=d, bias=True),
+            keys[0])
     else:
-        p["embed"] = {"table": jax.random.normal(
-            keys[0], (cfg.padded_vocab, d), jnp.float32) * d ** -0.5}
+        p["embed"] = make(lambda k: {"table": jax.random.normal(
+            k, (cfg.padded_vocab, d), jnp.float32) * d ** -0.5}, keys[0])
     if cfg.vision is not None:
-        p["vis_adapter"] = L.dense_init(keys[1], cfg.vision.embed_dim, d)
-    p["prefix"] = [block_init(k, cfg, s) for k, s in
+        p["vis_adapter"] = make(functools.partial(
+            L.dense_init, d_in=cfg.vision.embed_dim, d_out=d), keys[1])
+    blocks = {s: functools.partial(block_init, cfg=cfg, spec=s)
+              for s in cfg.all_blocks()}
+    p["prefix"] = [make(blocks[s], k) for k, s in
                    zip(jax.random.split(keys[2], max(1, len(cfg.prefix))),
                        cfg.prefix)]
-    p["suffix"] = [block_init(k, cfg, s) for k, s in
+    p["suffix"] = [make(blocks[s], k) for k, s in
                    zip(jax.random.split(keys[3], max(1, len(cfg.suffix))),
                        cfg.suffix)]
     pattern = []
     for i, s in enumerate(cfg.pattern):
         ks = jax.random.split(jax.random.fold_in(keys[4], i), cfg.repeats)
-        pattern.append(jax.vmap(lambda k: block_init(k, cfg, s))(ks))
+        if dtype is None:
+            pattern.append(jax.vmap(blocks[s])(ks))
+        else:
+            layers = [make(blocks[s], k) for k in ks]
+            pattern.append(jax.tree.map(lambda *a: jnp.stack(a), *layers))
     p["pattern"] = pattern
-    p["final_norm"] = L.norm_init(d)
-    p["lm_head"] = L.dense_init(keys[5], d, cfg.padded_vocab)
+    p["final_norm"] = make(functools.partial(L.norm_init, d))
+    p["lm_head"] = make(functools.partial(
+        L.dense_init, d_in=d, d_out=cfg.padded_vocab), keys[5])
     return p
 
 
@@ -130,11 +177,51 @@ def init_cache(cfg: ModelConfig, batch: int, ctx_len: int,
 
 # ---------------------------------------------------------------- blocks --
 
+def _zero_stats():
+    return {"aux": jnp.zeros((), jnp.float32),
+            "held_pairs": jnp.zeros((), jnp.int32)}
+
+
+def _operand(v, taps: bool):
+    """A residual add's operand.  Tapped, it is one materialized value
+    for the add and the tap: XLA on TPU may otherwise fuse its producer
+    (a bf16 matmul) into the add's quantization at excess precision, so
+    that the add reads other low bits than the tap shows."""
+    return jax.lax.optimization_barrier(v) if taps else v
+
+
+def _taps(cfg: ModelConfig, x, mix, mid, out, gates, ids):
+    """One block's intermediates (see :func:`block_apply`)."""
+    b, s, _ = x.shape
+    k = cfg.moe.experts_per_token if cfg.moe is not None else 0
+    return {"x": x, "mix": mix, "mid": mid, "out": out,
+            "gates": (jnp.zeros((b, s, k), jnp.float32)
+                      if gates is None else gates),
+            "ids": jnp.zeros((b, s, k), jnp.int32) if ids is None else ids}
+
+
 def block_apply(p: Params, cfg: ModelConfig, spec: BlockSpec, x, ctx,
                 cache: Optional[Params], mode: str, batch_axes=None,
-                mesh=None):
-    """mode: 'full' | 'prefill' | 'decode'. Returns (x, new_cache, aux)."""
-    h = L.rms_norm(p["ln1"], x, cfg.norm_eps)
+                mesh=None, layer=None, taps: bool = False):
+    """mode: 'full' | 'prefill' | 'decode'. Returns (x, new_cache, stats):
+    stats holds the router's ``aux`` loss and the ``held_pairs``, the
+    (token, slot) pairs that reached this chip's experts.
+
+    ``layer`` (decode only): ``cache`` is the pattern's stacked cache and
+    this block's entry is at that index of its leading axis.
+
+    ``taps``: stats also holds ``taps``, the block's intermediates: its
+    input ``x``, the mixer's output ``mix`` and the first residual add's
+    result ``mid``, the MLP's output ``out`` (the operands of the two
+    adds, in the residual stream's dtype) and, for an MoE MLP, each
+    token's ``gates`` and expert ``ids`` (zeros otherwise).  The block's
+    result is the next block's ``x``."""
+    x_in = x
+    h = L.rms_norm(p["ln1"], x, cfg.norm_eps).astype(COMPUTE_DTYPE)
+    stack = cache
+    sliced = layer is not None and spec.mixer not in IN_PLACE_DECODE
+    if sliced:
+        cache = jax.tree.map(lambda a: a[layer], stack)
     new_cache = cache
     if spec.mixer == ATTN:
         if mode == "full":
@@ -162,7 +249,8 @@ def block_apply(p: Params, cfg: ModelConfig, spec: BlockSpec, x, ctx,
                 p["mixer"], cfg, spec, h, ctx["positions"], cache)
         else:
             mix, new_cache = MLAm.mla_decode(
-                p["mixer"], cfg, spec, h, ctx["pos"], cache)
+                p["mixer"], cfg, spec, h, ctx["pos"], cache,
+                None if sliced else layer)
     elif spec.mixer == RGLRU:
         if mode == "full":
             mix, _ = RGm.rglru_apply(p["mixer"], cfg, spec, h)
@@ -180,25 +268,35 @@ def block_apply(p: Params, cfg: ModelConfig, spec: BlockSpec, x, ctx,
     else:
         raise ValueError(spec.mixer)
 
-    x = cfg.approx.residual_add(x, mix.astype(x.dtype))
-    aux = jnp.zeros((), jnp.float32)
+    if sliced:
+        new_cache = jax.tree.map(lambda a, n: a.at[layer].set(n), stack,
+                                 new_cache)
+    mix = _operand(mix.astype(x.dtype), taps)
+    x = cfg.approx.residual_add(x, mix)
+    stats = _zero_stats()
+    mid, out, route = x, jnp.zeros_like(x), {"gates": None, "ids": None}
     if spec.mlp != NONE:
-        h2 = L.rms_norm(p["ln2"], x, cfg.norm_eps)
+        h2 = L.rms_norm(p["ln2"], x, cfg.norm_eps).astype(COMPUTE_DTYPE)
         if spec.mlp == MOE:
             if cfg.moe.use_shard_map and mode != "decode":
-                out, aux = MOEm.moe_apply_shard_map(
+                out, st = MOEm.moe_apply_shard_map(
                     p["mlp"], cfg, h2, batch_axes=batch_axes, mesh=mesh)
             else:
-                out, aux = MOEm.moe_apply(p["mlp"], cfg, h2,
-                                          batch_axes=batch_axes)
+                out, st = MOEm.moe_apply(p["mlp"], cfg, h2,
+                                         batch_axes=batch_axes)
+            stats = {"aux": st["aux"], "held_pairs": st["held_pairs"]}
+            route = {"gates": st["gates"], "ids": st["ids"]}
         elif spec.mlp == SWIGLU:
             out = L.swiglu(p["mlp"], h2)
         else:
             out = L.gelu_mlp(p["mlp"], h2)
         if spec.mixer == CROSS:
             out = jnp.tanh(p["gate_mlp"]).astype(out.dtype) * out
-        x = cfg.approx.residual_add(x, out.astype(x.dtype))
-    return x, new_cache, aux
+        out = _operand(out.astype(x.dtype), taps)
+        x = cfg.approx.residual_add(x, out)
+    if taps:
+        stats["taps"] = _taps(cfg, x_in, mix, mid, out, **route)
+    return x, new_cache, stats
 
 
 # --------------------------------------------------------------- forward --
@@ -230,13 +328,21 @@ def embed_input(params, cfg: ModelConfig, batch, compute_dtype=jnp.bfloat16,
 
 def forward(params, cfg: ModelConfig, batch, *, mode: str = "full",
             cache: Optional[Params] = None, pos=None, batch_axes=None,
-            mesh=None, return_prelogits: bool = False):
-    """Returns (logits, new_cache, aux_sum)."""
+            mesh=None, return_prelogits: bool = False, taps: bool = False):
+    """Returns (logits, new_cache, stats), stats as :func:`block_apply`'s
+    summed over the blocks.  Decode takes ``pos`` of shape () or (B,),
+    one position per row; MLA blocks honour per-row positions.
+
+    ``taps``: stats also holds ``taps``, the blocks' intermediates (see
+    :func:`block_apply`) in the layout of the parameters (``prefix`` and
+    ``suffix`` lists, ``pattern`` stacked over the repeats), and
+    ``final``, the last block's result."""
     x, ctx = embed_input(params, cfg, batch, need_vision=(mode != "decode"))
+    if cfg.approx.enabled:
+        x = x.astype(jnp.float32)
     b, s = x.shape[:2]
     if mode == "decode":
         ctx["pos"] = pos
-        ctx["positions"] = pos[None]
     else:
         ctx["positions"] = jnp.arange(s, dtype=jnp.int32)
     # SP applies to full-sequence passes (training AND prefill); decode
@@ -244,12 +350,19 @@ def forward(params, cfg: ModelConfig, batch, *, mode: str = "full",
     ss = cfg.seq_shard and mode in ("full", "prefill")
     x = _shard_act(x, batch_axes, ss)
 
-    aux_total = jnp.zeros((), jnp.float32)
+    stats = _zero_stats()
+    tapped = {"prefix": [], "pattern": [], "suffix": []}
     empty = {"prefix": [None] * len(cfg.prefix),
              "suffix": [None] * len(cfg.suffix),
              "pattern": [None] * len(cfg.pattern)}
     cache_in = cache if cache is not None else empty
     cache_out = {"prefix": [], "suffix": [], "pattern": []}
+
+    def add(stats, st):
+        """Sums ``st`` into ``stats``; returns (stats, st's taps)."""
+        st = dict(st)
+        tap = st.pop("taps", None)
+        return jax.tree.map(jnp.add, stats, st), tap
 
     def apply_one(p, spec, x, c):
         if cfg.remat == "block" and mode == "full":
@@ -258,52 +371,73 @@ def forward(params, cfg: ModelConfig, batch, *, mode: str = "full",
                                   batch_axes=batch_axes, mesh=mesh))
             return fn(p, x=x, ctx=ctx, cache=c)
         return block_apply(p, cfg, spec, x, ctx, c, mode,
-                           batch_axes=batch_axes, mesh=mesh)
+                           batch_axes=batch_axes, mesh=mesh, taps=taps)
 
     for p, spec, c in zip(params["prefix"], cfg.prefix, cache_in["prefix"]):
-        x, nc, aux = apply_one(p, spec, x, c)
+        x, nc, st = apply_one(p, spec, x, c)
         x = _shard_act(x, batch_axes, ss)
         cache_out["prefix"].append(nc)
-        aux_total += aux
+        stats, tap = add(stats, st)
+        tapped["prefix"].append(tap)
 
     if cfg.repeats > 0 and cfg.pattern:
+        # Decode carries the stacked cache (see the module docstring);
+        # prefill scans over the cache's layers and stacks the new ones.
+        decode = mode == "decode"
+
         def body(carry, xs):
-            x, aux_acc = carry
+            x, stats, stacks, layer = carry
             pslices, cslices = xs
-            ys = []
+            stacks, ys, taps_ = list(stacks), [], []
             for i, spec in enumerate(cfg.pattern):
-                c = None if cslices is None else cslices[i]
-                x, nc, aux = block_apply(p=pslices[i], cfg=cfg, spec=spec,
-                                         x=x, ctx=ctx, cache=c, mode=mode,
-                                         batch_axes=batch_axes, mesh=mesh)
+                c = stacks[i] if decode else (
+                    None if cslices is None else cslices[i])
+                x, nc, st = block_apply(
+                    pslices[i], cfg, spec, x, ctx, c, mode,
+                    batch_axes=batch_axes, mesh=mesh,
+                    layer=layer if decode else None, taps=taps)
                 x = _shard_act(x, batch_axes, ss)
-                aux_acc = aux_acc + aux
-                ys.append(nc)
-            return (x, aux_acc), (tuple(ys) if cache is not None else 0)
+                stats, tap = add(stats, st)
+                taps_.append(tap)
+                if decode:
+                    stacks[i] = nc
+                else:
+                    ys.append(nc)
+            out = (tuple(ys) if cache is not None and not decode else 0,
+                   tuple(taps_))
+            return (x, stats, tuple(stacks), layer + 1), out
 
         if cfg.remat == "block" and mode == "full":
             body = jax.checkpoint(body)
-        cslices = tuple(cache_in["pattern"]) if cache is not None else None
-        (x, aux_total), ys = jax.lax.scan(
-            body, (x, aux_total),
-            (tuple(params["pattern"]), cslices) if cache is not None
-            else (tuple(params["pattern"]), None))
-        if cache is not None:
+        stacks = tuple(cache_in["pattern"]) if decode else ()
+        cslices = (tuple(cache_in["pattern"])
+                   if cache is not None and not decode else None)
+        (x, stats, stacks, _), (ys, taps_) = jax.lax.scan(
+            body, (x, stats, stacks, jnp.int32(0)),
+            (tuple(params["pattern"]), cslices))
+        if decode:
+            cache_out["pattern"] = list(stacks)
+        elif cache is not None:
             cache_out["pattern"] = list(ys)
+        tapped["pattern"] = list(taps_)
 
     for p, spec, c in zip(params["suffix"], cfg.suffix, cache_in["suffix"]):
-        x, nc, aux = apply_one(p, spec, x, c)
+        x, nc, st = apply_one(p, spec, x, c)
         x = _shard_act(x, batch_axes, ss)
         cache_out["suffix"].append(nc)
-        aux_total += aux
+        stats, tap = add(stats, st)
+        tapped["suffix"].append(tap)
+    if taps:
+        stats["taps"] = dict(tapped, final=x)
 
-    x = L.rms_norm(params["final_norm"], x, cfg.norm_eps)
+    x = L.rms_norm(params["final_norm"], x, cfg.norm_eps).astype(
+        COMPUTE_DTYPE)
     if mode in ("prefill", "decode") and cfg.causal:
         x = x[:, -1:]  # only the last position's logits are needed
     if return_prelogits:
-        return x, (cache_out if cache is not None else None), aux_total
+        return x, (cache_out if cache is not None else None), stats
     logits = L.dense(params["lm_head"], x)
-    return logits, (cache_out if cache is not None else None), aux_total
+    return logits, (cache_out if cache is not None else None), stats
 
 
 # ------------------------------------------------------------------ loss --
@@ -322,9 +456,10 @@ def softmax_cross_entropy(logits, labels):
 
 
 def loss_fn(params, cfg: ModelConfig, batch, batch_axes=None, mesh=None):
-    x, _, aux = forward(params, cfg, batch, mode="full",
-                        batch_axes=batch_axes, mesh=mesh,
-                        return_prelogits=True)
+    x, _, stats = forward(params, cfg, batch, mode="full",
+                          batch_axes=batch_axes, mesh=mesh,
+                          return_prelogits=True)
+    aux = stats["aux"]
 
     # Head + CE under remat: the (B, S, V) logits (and the fp32 softmax
     # internals) are recomputed during backward instead of being saved.

@@ -79,10 +79,9 @@ def test_smoke_prefill_decode_parity(name):
     logits_full, _, _ = T.forward(params, cfg, batch, mode="full")
     pre = dict(batch)
     pre["tokens"] = batch["tokens"][:, :s - 1]
-    logits_pre, cache = jax.jit(make_prefill_step(cfg, s))(params, pre)
-    logits_dec, _ = jax.jit(make_decode_step(cfg))(
-        params, {"tokens": batch["tokens"][:, s - 1:s]},
-        jnp.int32(s - 1), cache)
+    logits_pre, cache, _ = make_prefill_step(cfg, s)(params, pre)
+    logits_dec, _, _ = make_decode_step(cfg)(
+        params, batch["tokens"][:, s - 1:s], jnp.int32(s - 1), cache)
     a = np.asarray(logits_full[:, s - 2], np.float32)
     bb = np.asarray(logits_pre[:, 0], np.float32)
     c = np.asarray(logits_full[:, s - 1], np.float32)

@@ -156,3 +156,75 @@ def test_kernel_is_named_in_the_compiled_module(case, kernel, one_chip):
             for s, d in shapes]
     text = jax.jit(fn).lower(*args).compile().as_text()
     assert re.search(rf"%{kernel}(\.\d+)? = .*tpu_custom_call", text)
+
+
+def _dsv2_decode(one_chip):
+    """The DeepSeek-V2 cell's decode step at its published widths and
+    shapes (batch 64 over 16384 cache slots), compiled for one v5e."""
+    import json
+    import os
+
+    from chipbench.system import deepseek_v2
+    from repro.models import transformer as T
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "chipbench", "configs",
+                           "deepseek-v2-ep20-haloc16.json")) as f:
+        cfg = json.load(f)
+    system = deepseek_v2.build(cfg, "pallas_tpu")
+    model = system.model
+
+    def on_chip(tree):
+        return jax.tree.map(lambda a: jax.ShapeDtypeStruct(
+            a.shape, a.dtype, sharding=one_chip), tree)
+
+    params = on_chip(jax.eval_shape(lambda: T.init_params(
+        jax.random.key(0), model, dtype=jnp.bfloat16)))
+    cache = on_chip(jax.eval_shape(lambda: T.init_cache(
+        model, 64, 16384, jnp.bfloat16)))
+    tokens = jax.ShapeDtypeStruct((64, 1), jnp.int32, sharding=one_chip)
+    pos = jax.ShapeDtypeStruct((64,), jnp.int32, sharding=one_chip)
+    step = system.decode.__wrapped__
+    return step.lower(params, tokens, pos, cache).compile()
+
+
+@pytest.fixture(scope="module")
+def dsv2_decode(one_chip):
+    return _dsv2_decode(one_chip)
+
+
+#: One layer's latent cache in the DeepSeek-V2 cell: 64 rows of 16384
+#: slots of 576 bf16 values.
+DSV2_LAYER_CACHE = 64 * 16384 * (512 + 64) * 2
+
+
+def test_dsv2_decode_scopes_its_attention_and_experts(dsv2_decode):
+    """MLA decode and the expert layer run under ``mla:decode`` and
+    ``moe:experts``, which the compiled module's operations carry in
+    their metadata; the residual adds are the Pallas adder kernel."""
+    import re
+    text = dsv2_decode.as_text()
+    scopes = set()
+    for op_name in re.findall(r'op_name="([^"]*)"', text):
+        scopes.update(re.findall(r"mla:decode|moe:experts", op_name))
+    assert scopes == {"mla:decode", "moe:experts"}
+    assert re.search(r"%approx_add[.\d]* = .*tpu_custom_call", text)
+
+
+def test_dsv2_decode_updates_its_cache_in_place(dsv2_decode):
+    """The cache is donated: the output aliases all 6 GB of it, and the
+    step's temporaries stay far below one layer's cache (1.2 GB), so no
+    layer is copied."""
+    mem = dsv2_decode.memory_analysis()
+    assert mem.alias_size_in_bytes == 5 * DSV2_LAYER_CACHE
+    assert mem.temp_size_in_bytes < 0.5e9
+
+
+def test_dsv2_decode_copies_a_layer_when_mla_slices_its_cache(one_chip,
+                                                               monkeypatch):
+    """Why MLA is in ``transformer.IN_PLACE_DECODE``: when its layer is
+    sliced out of the stacked cache and set back, as the other mixers'
+    are, XLA copies the layer; the temporaries outgrow it."""
+    from repro.models import transformer as T
+    monkeypatch.setattr(T, "IN_PLACE_DECODE", frozenset())
+    mem = _dsv2_decode(one_chip).memory_analysis()
+    assert mem.temp_size_in_bytes > DSV2_LAYER_CACHE
