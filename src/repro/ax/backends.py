@@ -232,6 +232,11 @@ class Backend:
         the full MAC datapath."""
         raise NotImplementedError
 
+    def matmul_form(self, mul_spec: "MulSpec | None") -> dict:
+        """The form ``matmul`` takes for ``mul_spec``, as span
+        attributes; none where the backend has one form."""
+        return {}
+
     def butterfly(self, a_re, a_im, b_re, b_im, w_re, w_im, spec: AdderSpec,
                   *, inverse: bool = False):
         """One radix-2 FFT butterfly stage (exact Q1.14 twiddle multiplies,
@@ -772,14 +777,16 @@ def _pallas_elementwise_mul(a, b, mul_spec: MulSpec, interpret: bool,
                                     "interpret", "fast"))
 def _pallas_mac_matmul(a, b, spec: AdderSpec, mul_spec: MulSpec, block,
                        interpret: bool, fast: bool):
-    """Pad/slice plumbing for the MAC GEMM kernel.  Zero padding is
-    harmless in every dimension: a zero operand's product is 0, so
-    in-tile partials are unchanged, and padded M/N lanes are sliced
-    away."""
-    from repro.kernels.mac import mac_matmul_pallas
+    """Pad/slice plumbing for the MAC GEMM kernel, with operands in
+    int8 on its MXU form (a w <= 8-bit operand keeps its value mod 2^w)
+    and int32 on its VPU form.  Zero padding is harmless in every
+    dimension: a zero operand's product is 0, so in-tile partials are
+    unchanged, and padded M/N lanes are sliced away."""
+    from repro.kernels.mac import mac_matmul_pallas, plane_groups
     bm, bn, bk = block
-    ap, m0, _ = _pad2(a.astype(jnp.int32), bm, bk)
-    bp, _, n0 = _pad2(b.astype(jnp.int32), bk, bn)
+    dtype = jnp.int32 if plane_groups(mul_spec) is None else jnp.int8
+    ap, m0, _ = _pad2(a.astype(dtype), bm, bk)
+    bp, _, n0 = _pad2(b.astype(dtype), bk, bn)
     out = mac_matmul_pallas(ap, bp, spec, mul_spec, block=block,
                             interpret=interpret, fast=fast)
     return out[:m0, :n0]
@@ -852,6 +859,12 @@ class PallasBackend(Backend):
         return _pallas_matmul(jnp.asarray(a), jnp.asarray(b), spec,
                               tuple(block), self.interpret,
                               _fast(strategy))
+
+    def matmul_form(self, mul_spec):
+        from repro.kernels.mac import mac_form
+        if mul_spec is None or mul_spec.is_exact:
+            return {}
+        return mac_form(mul_spec)
 
     def butterfly(self, a_re, a_im, b_re, b_im, w_re, w_im, spec, *,
                   inverse=False):

@@ -242,7 +242,8 @@ class AxEngine:
         MAC engine (``mul_spec`` set) every product additionally runs
         the approximate multiplier."""
         with _obs.span("ax:matmul", kind=self.spec.kind,
-                       backend=self.backend.name):
+                       backend=self.backend.name,
+                       **self.backend.matmul_form(self.mul_spec)):
             return self.backend.matmul(a, b, self.spec, block=block,
                                        strategy=self.strategy,
                                        mul_spec=self.mul_spec)

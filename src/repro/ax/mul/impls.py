@@ -45,7 +45,8 @@ def _ones(width: int) -> int:
 
 # ------------------------------------------------------------ accurate --
 
-@register_multiplier("accurate", order=0, is_exact=True)
+@register_multiplier("accurate", order=0, is_exact=True,
+                     row_cut=lambda spec, i: 0)
 def accurate_mul(a, b, spec):
     """Exact array multiplier (the baseline)."""
     return a * b
@@ -68,8 +69,13 @@ def truncated_mul_fast(a, b, spec):
     return a * b - d
 
 
+def _truncated_cut(spec, i):
+    return max(spec.effective_trunc_bits - i, 0)
+
+
 @register_multiplier("truncated", order=1, uses_trunc=True,
-                     fast_impl=truncated_mul_fast, low_delta=True)
+                     fast_impl=truncated_mul_fast, low_delta=True,
+                     row_cut=_truncated_cut)
 def truncated_mul(a, b, spec):
     """Column-truncated array multiplier (reference form).
 
@@ -101,9 +107,13 @@ def broken_array_mul_fast(a, b, spec):
     return ah * b - d
 
 
+def _broken_array_cut(spec, i):
+    return max(spec.effective_row_bits, spec.effective_trunc_bits - i)
+
+
 @register_multiplier("broken_array", order=2, uses_trunc=True,
                      uses_rows=True, fast_impl=broken_array_mul_fast,
-                     low_delta=True)
+                     low_delta=True, row_cut=_broken_array_cut)
 def broken_array_mul(a, b, spec):
     """Broken-array multiplier (reference form): cell ``(i, j)``
     survives iff ``j >= max(vbl, hbl - i)``."""

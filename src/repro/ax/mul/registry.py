@@ -58,6 +58,13 @@ class MulImpl:
         ``(a mod 2^t, b mod 2^t)`` with ``t = effective_trunc_bits``
         whenever ``effective_row_bits == 0`` — what unlocks the
         factorized closed-form MRED (:mod:`repro.ax.analytics`).
+      row_cut: for a pruned array multiplier, ``f(spec, i) -> c``: row
+        ``i`` (multiplier bit ``i``) keeps the multiplicand bits at and
+        above ``c``, so ``approx(a, b) = sum_i 2^i b_i (a with its low
+        c(i) bits cleared)`` exactly — what lets the MAC GEMM kernel
+        run its products as grouped plane matmuls
+        (:mod:`repro.kernels.mac`).  ``None`` for kinds of any other
+        form.
     """
 
     kind: str
@@ -69,6 +76,7 @@ class MulImpl:
     uses_rows: bool = False
     trunc_margin: int = 0
     low_delta: bool = False
+    row_cut: Optional[Callable] = None
 
     def select(self, fast: bool) -> Callable:
         """The implementation to run: fused when requested and available."""
@@ -85,7 +93,8 @@ _BUILTINS_LOADED = False
 def register_multiplier(kind: str, *, fast_impl: Optional[Callable] = None,
                         order: int = 1000, is_exact: bool = False,
                         uses_trunc: bool = False, uses_rows: bool = False,
-                        trunc_margin: int = 0, low_delta: bool = False):
+                        trunc_margin: int = 0, low_delta: bool = False,
+                        row_cut: Optional[Callable] = None):
     """Decorator registering a reference multiplier implementation.
 
     Returns the decorated function unchanged, so the module keeps its
@@ -97,7 +106,8 @@ def register_multiplier(kind: str, *, fast_impl: Optional[Callable] = None,
         entry = MulImpl(
             kind=kind, impl=fn, fast_impl=fast_impl, order=order,
             is_exact=is_exact, uses_trunc=uses_trunc, uses_rows=uses_rows,
-            trunc_margin=trunc_margin, low_delta=low_delta)
+            trunc_margin=trunc_margin, low_delta=low_delta,
+            row_cut=row_cut)
         with _LOCK:
             prev = _MULS.get(kind)
             if prev is not None and prev.impl is not fn:

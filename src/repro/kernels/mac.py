@@ -1,23 +1,42 @@
 """Pallas MAC kernels: approximate products composed with approximate
 accumulation, VMEM-resident.
 
-Three entry points, mirroring the adder-side kernel set.  Every product
-is computed in the kernel body by the registered multiplier impl
-(:func:`repro.ax.mul.impls.approx_mul`): Mosaic lowers only 2-D
-gathers, so no product table rides along as a VMEM operand.
+Three entry points, mirroring the adder-side kernel set.  A product
+computed on the VPU runs the registered multiplier impl
+(:func:`repro.ax.mul.impls.approx_mul`) in the kernel body: Mosaic
+lowers only 2-D gathers, so no product table rides along as a VMEM
+operand.
 
 * :func:`mul_elementwise_pallas` — the elementwise approximate
   multiplier on (256, 256) int32 tiles.
 
-* :func:`mac_matmul_pallas` — signed MAC GEMM.  Where the exact-product
-  kernel (``approx_matmul.py``) feeds the MXU, an approximate-multiplier
-  MAC array has nothing to ship to the MXU: each K step multiplies one
-  column of the A tile (rotated into lane 0) by one row of the B tile
-  in sign-magnitude form, and the products accumulate EXACTLY within
-  the K tile (int32 wraparound is associative mod 2^32, so in-tile
-  order cannot matter), with the approximate adder on the inter-tile
-  accumulator — the same placement as the adder-only kernel.  Grid
-  (M/bm, N/bn, K/bk), K innermost, output block revisited.
+* :func:`mac_matmul_pallas` — signed MAC GEMM, grid (M/bm, N/bn,
+  K/bk), K innermost, output block revisited.  The products of a K
+  tile sum EXACTLY into an int32 partial (int32 wraparound is
+  associative mod 2^32, so in-tile order cannot matter); the
+  approximate adder folds the partials across K tiles — the same
+  placement as the adder-only kernel.  The partial takes one of two
+  forms, chosen by the multiplier spec (:func:`plane_groups`):
+
+  - *MXU planes*, for a pruned array multiplier (the registry's
+    ``row_cut``) at ``n_bits <= 8``.  Row ``i`` of such an array keeps
+    the multiplicand bits at and above a cut ``c(i)``; grouping the
+    rows by cut, with ``bits(c)`` the mask of a group's rows,
+
+        sum_k sign(a) sign(b) approx(|a|, |b|)
+            = sum_c (sign(a) (|a| with its low c bits cleared))
+                    @ (sign(b) (|b| & bits(c)))
+
+    exactly.  Both factors lie in [-128, 127] (a magnitude of 128
+    only at -128), so each group is one int8 MXU matmul with int32
+    sums: a product is at most 2^14 and a tile's sum stays far inside
+    int32.  The masks carry the 2^i row weights, so nothing is scaled
+    after the dot.  Truncated n8t4 has cuts 4, 3, 2, 1, 0, 0, 0, 0:
+    five matmuls a tile step.
+  - *VPU columns*, for every other multiplier (Mitchell) and for
+    ``n_bits > 8``, whose planes no longer fit int8: each K step
+    multiplies one column of the A tile (rotated into lane 0) by one
+    row of the B tile with the registered multiplier.
 
 * :func:`conv2d_mac_pallas` — the 2D MAC convolution: per-tap
   sign-magnitude products against the static kernel weights, folded
@@ -25,8 +44,8 @@ gathers, so no product table rides along as a VMEM operand.
   One program per (image, row block) with an in-kernel halo, exactly
   like the filter-chain kernel (:mod:`repro.kernels.stencil`).
 
-All three are bit-identical to the jax/numpy MAC paths by construction:
-the sign-magnitude rule and the operand order are those of the product
+All three are bit-identical to the jax/numpy MAC paths: the
+sign-magnitude rule and the operand order are those of the product
 tables the other backends gather from (``repro.ax.mul.lut``), and the
 fold order is the same.
 """
@@ -42,6 +61,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.ax.mul.impls import approx_mul
+from repro.ax.mul.registry import get_multiplier
 from repro.ax.mul.specs import MulSpec
 from repro.core.adders import approx_add_mod
 from repro.core.specs import AdderSpec
@@ -88,23 +108,75 @@ def _signed_operand(v, w: int):
     return mag, (s > 0).astype(jnp.int32) - (s < 0).astype(jnp.int32)
 
 
-def _mac_matmul_kernel(a_ref, b_ref, o_ref, *, spec: AdderSpec,
-                       mul_spec: MulSpec, fast: bool, bk: int):
-    bm, bn = o_ref.shape
+@functools.lru_cache(maxsize=None)
+def plane_groups(mul_spec: MulSpec):
+    """The MXU form's groups ``((cut, rows), ...)``, largest cut first —
+    ``rows`` the mask of the multiplier bits whose row keeps the
+    multiplicand at and above ``cut`` — or ``None`` where the partial
+    takes the VPU column loop (no registered ``row_cut``, or operands
+    wider than int8)."""
+    row_cut = get_multiplier(mul_spec.kind).row_cut
+    if row_cut is None or mul_spec.n_bits > 8:
+        return None
+    groups = {}
+    for i in range(mul_spec.n_bits):
+        cut = row_cut(mul_spec, i)
+        groups[cut] = groups.get(cut, 0) | (1 << i)
+    return tuple(sorted(groups.items(), reverse=True))
+
+
+def mac_form(mul_spec: MulSpec) -> dict:
+    """The form the MAC GEMM kernel takes for ``mul_spec``, and its
+    plane matmuls per K tile."""
+    groups = plane_groups(mul_spec)
+    if groups is None:
+        return {"form": "vpu_columns", "mxu_dots": 0}
+    return {"form": "mxu_planes", "mxu_dots": len(groups)}
+
+
+def _plane_partial(a, b, w: int, groups):
+    """One K tile's exact partial as grouped plane matmuls on the MXU."""
+    amag, asgn = _signed_operand(a.astype(jnp.int32), w)
+    bmag, bsgn = _signed_operand(b.astype(jnp.int32), w)
+    partial = None
+    for cut, rows in groups:
+        lhs = asgn * ((amag >> cut) << cut).astype(jnp.int32)
+        rhs = bsgn * (bmag & rows).astype(jnp.int32)
+        dot = jnp.dot(lhs.astype(jnp.int8), rhs.astype(jnp.int8),
+                      preferred_element_type=jnp.int32)
+        partial = dot if partial is None else partial + dot
+    return partial
+
+
+def _column_partial(a_ref, b_ref, shape, mul_spec: MulSpec, fast: bool,
+                    bk: int):
+    """One K tile's exact partial, one K column a loop step on the VPU."""
     w = mul_spec.n_bits
 
     def step(j, carry):
         a, part = carry                    # a: (bm, bk), column j at lane 0
         amag, asgn = _signed_operand(a[:, :1], w)
         bmag, bsgn = _signed_operand(b_ref[pl.ds(j, 1), :], w)
-        p = approx_mul(jnp.broadcast_to(amag, (bm, bn)),
-                       jnp.broadcast_to(bmag, (bm, bn)), mul_spec,
+        p = approx_mul(jnp.broadcast_to(amag, shape),
+                       jnp.broadcast_to(bmag, shape), mul_spec,
                        fast=fast)
         p = jax.lax.bitcast_convert_type(p, jnp.int32) * (asgn * bsgn)
         return pltpu.roll(a, bk - 1, 1), part + p
 
     _, partial = jax.lax.fori_loop(
-        0, bk, step, (a_ref[...], jnp.zeros((bm, bn), jnp.int32)))
+        0, bk, step, (a_ref[...], jnp.zeros(shape, jnp.int32)))
+    return partial
+
+
+def _mac_matmul_kernel(a_ref, b_ref, o_ref, *, spec: AdderSpec,
+                       mul_spec: MulSpec, fast: bool, bk: int):
+    groups = plane_groups(mul_spec)
+    if groups is None:
+        partial = _column_partial(a_ref, b_ref, o_ref.shape, mul_spec,
+                                  fast, bk)
+    else:
+        partial = _plane_partial(a_ref[...], b_ref[...], mul_spec.n_bits,
+                                 groups)
 
     @pl.when(pl.program_id(2) == 0)
     def _init():
@@ -121,12 +193,15 @@ def _mac_matmul_kernel(a_ref, b_ref, o_ref, *, spec: AdderSpec,
 def mac_matmul_pallas(a, b, spec: AdderSpec, mul_spec: MulSpec, *,
                       interpret: bool, block=(128, 128, 128),
                       fast: bool = False):
-    """a: int32 (M, K); b: int32 (K, N) -> int32 (M, N), operands read
-    as signed ``mul_spec.n_bits``-bit values.
+    """a: (M, K); b: (K, N) -> int32 (M, N), operands read as signed
+    ``mul_spec.n_bits``-bit values: int8 where :func:`plane_groups`
+    gives the MXU form, int32 on the VPU column loop.
 
     Every product is ``sign(a) * sign(b) * approx(|a|, |b|)`` (zero for
     a zero operand, so callers may zero-pad ragged K tiles without
-    changing the result); in-tile accumulation is exact int32,
+    changing the result); in-tile accumulation is exact int32 — as
+    grouped plane matmuls on the MXU for a pruned array multiplier at
+    ``n_bits <= 8``, else one K column a step on the VPU — and
     inter-tile accumulation runs the approximate adder."""
     m, k = a.shape
     k2, n = b.shape

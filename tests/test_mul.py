@@ -261,15 +261,93 @@ def test_strategies_share_one_error_report():
 
 # --------------------------------------------------------- MAC datapaths --
 
-def test_mac_matmul_bit_identical_across_backends():
-    """MAC GEMM (approximate products + approximate accumulation) is
-    bit-identical on numpy/jax/pallas with ragged K (inter-tile
-    approximate folds exercised), and differs from the exact-product
-    path."""
+def _pruned_array_specs(max_bits=8):
+    """Every truncated and broken_array spec at n_bits <= max_bits."""
+    for n in range(2, max_bits + 1):
+        for t in range(n + 1):
+            yield MulSpec("truncated", n, t)
+            for v in range(n + 1):
+                yield MulSpec("broken_array", n, t, v)
+
+
+def test_plane_groups_identity_exhaustive():
+    """The MXU form's identity, for every signed operand pair of every
+    truncated and broken_array spec at n_bits <= 8: the grouped plane
+    products sum to sign(a) sign(b) approx(|a|, |b|) exactly, and every
+    plane factor fits int8."""
+    from repro.kernels.mac import plane_groups
+    for spec in _pruned_array_specs():
+        w = spec.n_bits
+        vals = np.arange(-(1 << (w - 1)), 1 << (w - 1), dtype=np.int64)
+        sa, sb = np.repeat(vals, vals.size), np.tile(vals, vals.size)
+        want = np.sign(sa) * np.sign(sb) * approx_mul(
+            np.abs(sa).astype(np.uint64), np.abs(sb).astype(np.uint64),
+            spec).astype(np.int64)
+        got = np.zeros_like(want)
+        groups = plane_groups(spec)
+        assert groups, spec
+        for cut, rows in groups:
+            lhs = np.sign(sa) * (np.abs(sa) & -(1 << cut))
+            rhs = np.sign(sb) * (np.abs(sb) & rows)
+            for f in (lhs, rhs):
+                assert f.min() >= -128 and f.max() <= 127, spec
+            got += lhs * rhs
+        np.testing.assert_array_equal(got, want, err_msg=spec.short_name)
+
+
+def test_plane_groups_select_the_form():
+    """Truncated n8t4 runs five plane matmuls a K tile; Mitchell and
+    operands wider than int8 keep the column loop."""
+    from repro.kernels.mac import plane_groups
+    assert plane_groups(MulSpec("truncated", 8, 4)) == (
+        (4, 0b1), (3, 0b10), (2, 0b100), (1, 0b1000), (0, 0b11110000))
+    assert plane_groups(MulSpec("accurate", 8)) == ((0, 0xFF),)
+    assert plane_groups(MulSpec("broken_array", 8, 0, 3)) == ((3, 0xFF),)
+    assert plane_groups(MulSpec("mitchell", 8)) is None
+    assert plane_groups(MulSpec("truncated", 9, 4)) is None
+
+
+def _mac_operands(case):
+    """(a, b) of a MAC GEMM test case: seeded random signed operands,
+    or every element at one value."""
+    shape_k, fill, dtype = case
+    m, k, n = shape_k
+    if fill is not None:
+        return (np.full((m, k), fill, np.int8),
+                np.full((k, n), fill, np.int8))
     rng = np.random.default_rng(21)
-    a = rng.integers(-128, 128, size=(16, 300), dtype=np.int8)
-    b = rng.integers(-128, 128, size=(300, 24), dtype=np.int8)
-    mul = MulSpec("truncated", 8, 3)
+    lo, hi = np.iinfo(dtype).min, np.iinfo(dtype).max
+    lo, hi = max(lo, -512), min(hi, 512)
+    return (rng.integers(lo, hi, size=(m, k)).astype(dtype),
+            rng.integers(lo, hi, size=(k, n)).astype(dtype))
+
+
+@pytest.mark.parametrize("mul,case,drops", [
+    pytest.param(MulSpec("truncated", 8, 3), ((16, 300, 24), None, np.int8),
+                 True, id="truncated_n8t3_k_ragged"),
+    pytest.param(MulSpec("truncated", 8, 3), ((16, 256, 24), None, np.int8),
+                 True, id="truncated_n8t3_k_whole_tiles"),
+    pytest.param(MulSpec("truncated", 8, 4), ((16, 300, 24), None, np.int8),
+                 True, id="truncated_n8t4"),
+    pytest.param(MulSpec("broken_array", 8, 5, 2),
+                 ((16, 300, 24), None, np.int8), True,
+                 id="broken_array_t5v2"),
+    pytest.param(MulSpec("truncated", 6, 2), ((16, 300, 24), None, np.int32),
+                 True, id="truncated_n6_wrap"),
+    pytest.param(MulSpec("truncated", 8, 4), ((16, 256, 24), -128, None),
+                 False, id="all_minus_128"),
+    pytest.param(MulSpec("truncated", 8, 4), ((16, 256, 24), 127, None),
+                 True, id="all_127"),
+    pytest.param(MulSpec("mitchell", 8), ((16, 200, 24), None, np.int8),
+                 True, id="mitchell_columns"),
+])
+def test_mac_matmul_bit_identical_across_backends(mul, case, drops):
+    """MAC GEMM (approximate products + approximate accumulation) is
+    bit-identical on numpy/jax/pallas — the Pallas kernel on its MXU
+    plane form or, for Mitchell, its column loop — with inter-tile
+    approximate folds exercised, and (where the multiplier drops mass
+    at these operands) differs from the exact-product path."""
+    a, b = _mac_operands(case)
     for spec in (paper_spec("haloc_axa"), ADDER16):
         want = np.asarray(get_backend("numpy").matmul(
             a, b, spec, strategy="reference", mul_spec=mul))
@@ -283,8 +361,34 @@ def test_mac_matmul_bit_identical_across_backends():
         got = get_backend("jax").matmul(a, b, spec, strategy="lut",
                                         mul_spec=mul)
         np.testing.assert_array_equal(np.asarray(got), want)
-        exact = np.asarray(get_backend("numpy").matmul(a, b, spec))
-        assert not np.array_equal(exact, want)
+        if drops:
+            exact = np.asarray(get_backend("numpy").matmul(a, b, spec))
+            assert not np.array_equal(exact, want)
+
+
+@pytest.mark.parametrize("mul,form,dots", [
+    (MulSpec("truncated", 8, 4), "mxu_planes", 5),
+    (MulSpec("mitchell", 8), "vpu_columns", 0),
+])
+def test_mac_matmul_span_names_its_form(mul, form, dots, capture):
+    """With telemetry on, the ``ax:matmul`` span of a MAC engine on the
+    Pallas backend says which form the kernel took and how many plane
+    matmuls it runs a K tile."""
+    from repro import obs
+    engine = make_engine(MacSpec(paper_spec("haloc_axa"), mul),
+                         backend="pallas")
+    a = np.ones((8, 128), np.int8)
+    obs.reset_all()
+    obs.enable()
+    try:
+        with capture() as cap:
+            engine.matmul(a, a.T).block_until_ready()
+    finally:
+        obs.disable()
+        obs.reset_all()
+    span, = cap.named("ax:matmul")
+    assert span.stats["form"] == form
+    assert span.stats["mxu_dots"] == dots
 
 
 def test_mac_matmul_exact_mul_spec_is_backcompat():

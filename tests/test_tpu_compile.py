@@ -88,6 +88,15 @@ def _mac_matmul(mul):
             [((256, 256), jnp.int8)] * 2)
 
 
+def _mac_matmul_conv2():
+    """conv2_x's GEMM tile rows with the GEMM cell's specs: int8
+    (1024, 576) @ (576, 64), truncated n8t4 products."""
+    mul = MulSpec("truncated", 8, 4)
+    return (lambda a, b: TPU.matmul(a, b, SPEC32, strategy="fused",
+                                    mul_spec=mul),
+            [((1024, 576), jnp.int8), ((576, 64), jnp.int8)])
+
+
 def _conv2d():
     mul = MulSpec("truncated", 8, 4)
     return (lambda q: TPU.conv2d(q, SPEC16, mul, KERNEL3, shift=4,
@@ -116,6 +125,7 @@ CASES = {
     "mac_matmul_truncated_256": lambda: _mac_matmul(
         MulSpec("truncated", 8, 4)),
     "mac_matmul_mitchell_256": lambda: _mac_matmul(MulSpec("mitchell", 8)),
+    "mac_matmul_truncated_conv2": _mac_matmul_conv2,
     "conv2d_mac_4x256": _conv2d,
     "butterfly_512x256": _butterfly,
 }
@@ -145,8 +155,11 @@ def test_downsample_compiles_without_gathers(case, one_chip):
     assert re.search(r"\bslice\(", text)
 
 
-@pytest.mark.parametrize("case,kernel", [("accumulate_k9_1024", "accumulate"),
-                                         ("fused_add_1024", "approx_add")])
+@pytest.mark.parametrize("case,kernel", [
+    ("accumulate_k9_1024", "accumulate"),
+    ("fused_add_1024", "approx_add"),
+    ("mac_matmul_truncated_256", "mac_matmul"),
+])
 def test_kernel_is_named_in_the_compiled_module(case, kernel, one_chip):
     """The ``pallas_call`` carries its ``name``: XLA names the Mosaic
     custom call after it, and so does the device trace."""
