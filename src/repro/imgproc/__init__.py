@@ -16,9 +16,10 @@ in bounded memory, bit-identical to untiled execution, a workload
 registry that hosts the operators, the stock pipelines and the
 FFT->IFFT reconstruction formerly one-off in ``repro.image.pipeline``,
 and a corpus runner that sweeps {adder kinds} x {workloads} x {image
-batch} into PSNR/SSIM/throughput tables plus an async double-buffered
-stream executor (``run_streaming``) for steady-state megapixel
-throughput (``benchmarks/bench_imgproc.py``).
+batch} into PSNR/SSIM/throughput tables, plus a pipelined dispatch/fetch
+loop (``run_streaming``) that keeps up to ``depth`` batches in flight
+for steady-state megapixel throughput.  Retries, deadlines and
+per-request isolation belong to :mod:`repro.serving`.
 
     from repro.imgproc import make_image_engine, box_blur, run_corpus
 

@@ -9,6 +9,10 @@ float reference with PSNR/SSIM plus measured throughput.
     from repro.imgproc import run_corpus, format_table
     rows = run_corpus()            # TABLE1_KINDS x batched workloads
     print(format_table(rows))
+
+The module also holds :func:`run_streaming`, the pipelined
+dispatch/fetch loop that keeps up to ``depth`` batches in flight for
+steady-state throughput.
 """
 
 from __future__ import annotations
@@ -179,33 +183,17 @@ class StreamResult:
     that window — the number a serving deployment sees, transfer and
     host round-trips included.
 
-    ``batch_seconds`` holds each accepted batch's observed latency
-    (dispatch to drained-on-host, so with ``depth > 1`` in-flight
-    waiting counts — it is the latency a caller of this runner
-    experiences, not pure device time).  The ``p50/p95/p99`` properties
-    summarize it; they are ``nan`` for results predating the field (old
-    pickles) or empty streams.
+    ``batch_seconds`` holds each batch's observed latency (dispatch to
+    drained-on-host, so with ``depth > 1`` in-flight waiting counts —
+    it is the latency a caller of this runner experiences, not pure
+    device time).  The ``p50/p95/p99`` properties summarize it; they
+    are ``nan`` for results predating the field (old pickles) or empty
+    streams."""
 
-    The hardened-runner fields record what went wrong and what the
-    runner did about it (all empty on a clean run, so results from
-    before the fields existed unpickle/compare unchanged):
-
-    - ``failed``: indices of poisoned batches that raised under
-      ``isolate=True`` — their ``outputs`` slot holds ``None``.
-    - ``retried``: indices that missed their deadline (or were flagged
-      as straggler outliers) at least once and were re-dispatched with
-      exponential backoff.
-    - ``degraded``: indices that ran on a
-      :class:`~repro.resilience.degrade.DegradePolicy` fallback plan
-      (the batch that tripped the policy is re-run and included)."""
-
-    outputs: List[Optional[np.ndarray]]
+    outputs: List[np.ndarray]
     seconds: float
     pixels: int
     batch_seconds: Tuple[float, ...] = ()
-    failed: Tuple[int, ...] = ()
-    retried: Tuple[int, ...] = ()
-    degraded: Tuple[int, ...] = ()
 
     @property
     def mpix_per_s(self) -> float:
@@ -235,8 +223,6 @@ class _InFlight:
     t: float                   # dispatch wall-clock (perf_counter)
     fut: object                # device future (or host array)
     index: int                 # position in the input stream
-    batch: object              # kept for re-dispatch on retry
-    attempt: int               # 0 = first dispatch
 
 
 def _settle(fut) -> None:
@@ -244,8 +230,8 @@ def _settle(fut) -> None:
 
     Teardown helper: a future we will not use must still be settled so
     the device queue drains and no async error escapes after the runner
-    returns.  Any exception it raises was already accounted for (or is
-    being superseded by the one unwinding the stack)."""
+    returns.  Any exception it raises is superseded by the one
+    unwinding the stack."""
     try:
         np.asarray(fut)
     except Exception:
@@ -253,15 +239,8 @@ def _settle(fut) -> None:
 
 
 def run_streaming(fn: Callable, batches: Iterable[np.ndarray], *,
-                  depth: int = 2,
-                  deadline_s: Optional[float] = None,
-                  max_retries: int = 2,
-                  backoff_s: float = 0.05,
-                  isolate: bool = False,
-                  retry_failures: bool = False,
-                  straggler=None,
-                  degrade=None) -> StreamResult:
-    """Async double-buffered executor: dispatch batch ``i+1`` BEFORE
+                  depth: int = 2) -> StreamResult:
+    """Pipelined dispatch and fetch: dispatch batch ``i+1`` BEFORE
     blocking on batch ``i``'s result.
 
     jax dispatch is asynchronous: ``fn(batch)`` returns a device array
@@ -269,74 +248,29 @@ def run_streaming(fn: Callable, batches: Iterable[np.ndarray], *,
     is materialized (``np.asarray``).  A naive loop serializes
     host-side work (input staging, output copy, python) with device
     compute; this runner keeps up to ``depth`` batches in flight, so
-    the device starts batch ``i+1`` while the host drains batch ``i`` —
-    the steady-state pipeline the ROADMAP's serving story needs.  With
-    ``depth=1`` it degrades to the naive blocking loop (the benchmark's
-    comparison baseline).
+    the device starts batch ``i+1`` while the host drains batch ``i``.
+    With ``depth=1`` it is the naive blocking loop.
 
     ``fn`` is any compiled callable returning device (or host) arrays —
     a :class:`~repro.imgproc.plan.CompiledPipeline` or a tiled executor
-    from :func:`repro.imgproc.tiles.compile_tiled`.  Outputs are
+    from :func:`repro.imgproc.tiles.compile_tiled`.  ``batches`` may be
+    any iterable, including a generator that stops early.  Outputs are
     returned in input order, materialized on the host.
 
-    Hardening (all off by default — the plain call is byte-identical to
-    the historical runner):
-
-    - ``deadline_s`` / ``straggler``: per-batch latency SLO.  Lateness
-      is judged by :meth:`repro.runtime.straggler.StragglerMonitor.late`
-      — the repo's one lateness definition — against the explicit
-      deadline and, when a ``StragglerConfig`` is passed, the stream's
-      own median/MAD history.  A late batch is re-dispatched up to
-      ``max_retries`` times with exponential backoff
-      (``backoff_s * 2**attempt``); its index lands in ``retried``.
-    - ``isolate=True``: a batch that raises (dispatch or drain) is a
-      recorded failure — ``None`` in ``outputs``, index in ``failed`` —
-      instead of killing the stream.  With ``isolate=False`` the error
-      re-raises as ``RuntimeError`` naming the failing batch index, and
-      every still-pending future is drained or dropped first: an
-      exception can never leak in-flight work.
-    - ``retry_failures=True``: a RAISING batch is also re-dispatched up
-      to ``max_retries`` times with the same exponential backoff
-      (transient device faults recover; its index lands in
-      ``retried``).  A batch that fails EVERY attempt then takes the
-      ``isolate`` path: recorded in ``failed`` (or re-raised when
-      ``isolate=False``) with its exhausted-attempt count in the error.
-    - ``degrade``: a :class:`~repro.resilience.degrade.DegradePolicy`.
-      Each batch is shown to the policy after dispatch; when the
-      policy's drift monitor trips, the in-flight future is settled and
-      the batch re-runs on the recovered (next-cheapest Pareto) plan,
-      which also serves every subsequent batch.  Affected indices land
-      in ``degraded``.
+    A batch that raises, at dispatch or while draining, ends the stream
+    with a ``RuntimeError`` naming its index; every still-pending
+    future is settled first, so an exception never leaks in-flight
+    work.  Retries, deadlines and per-request isolation belong to
+    :class:`repro.serving.Scheduler`; degrading a stream to a fallback
+    plan to :meth:`repro.resilience.degrade.DegradePolicy.stream`.
     """
     if depth < 1:
         raise ValueError(f"depth must be >= 1; got {depth}")
-    if deadline_s is not None and not deadline_s > 0:
-        raise ValueError(f"deadline_s must be > 0; got {deadline_s}")
-    if max_retries < 0:
-        raise ValueError(f"max_retries must be >= 0; got {max_retries}")
-    if backoff_s < 0:
-        raise ValueError(f"backoff_s must be >= 0; got {backoff_s}")
-
-    watch = None
-    if deadline_s is not None or straggler is not None:
-        from repro.runtime.straggler import (StragglerConfig,
-                                             StragglerMonitor)
-        # Deadline-only callers get a monitor whose outlier filter can
-        # never fire (min_samples unreachable): late() then reduces to
-        # the explicit-deadline check, but stays routed through the one
-        # shared lateness definition.
-        cfg = straggler if straggler is not None else StragglerConfig(
-            min_samples=1 << 30)
-        watch = StragglerMonitor(cfg)
 
     pending: collections.deque = collections.deque()
-    results: dict = {}
+    outputs: List[np.ndarray] = []
     latencies: List[float] = []
-    failed: List[int] = []
-    retried: List[int] = []
-    degraded: List[int] = []
     pixels = 0
-    count = 0
     instrumented = _obs._ENABLED
     if instrumented:
         in_flight = _metrics.gauge("stream.batches_in_flight")
@@ -344,19 +278,12 @@ def run_streaming(fn: Callable, batches: Iterable[np.ndarray], *,
         n_batches = _metrics.counter("stream.batches")
         n_pixels = _metrics.counter("stream.pixels")
         n_failed = _metrics.counter("stream.failed_batches")
-        n_retried = _metrics.counter("stream.retries")
 
-    # The active callable: degradation swaps it mid-stream, and retry
-    # re-dispatch must pick up the swapped plan, so it lives in a cell.
-    active = [fn]
-
-    def dispatch(batch, index: int, attempt: int) -> None:
-        t = time.perf_counter()
-        with _obs.span("stream:dispatch", batch=index, attempt=attempt):
-            fut = active[0](batch)
+    def fail(index: int, where: str, exc: Exception) -> RuntimeError:
         if instrumented:
-            in_flight.inc()
-        pending.append(_InFlight(t, fut, index, batch, attempt))
+            n_failed.inc()
+        return RuntimeError(
+            f"run_streaming: batch {index} failed {where}: {exc}")
 
     def drain() -> None:
         # Draining materializes the device future on the host: THE sync
@@ -372,105 +299,33 @@ def run_streaming(fn: Callable, batches: Iterable[np.ndarray], *,
             else:
                 out = np.asarray(ent.fut)
         except Exception as exc:
+            raise fail(ent.index, "while draining", exc) from exc
+        finally:
             if instrumented:
                 in_flight.dec()
-            attempt = ent.attempt
-            if retry_failures:
-                # Transient-fault path: a raising batch re-dispatches
-                # with the same exponential backoff as the deadline
-                # path.  A re-dispatch that itself raises consumes the
-                # next attempt, so a hard-poisoned batch exhausts its
-                # budget here instead of looping forever.
-                while attempt < max_retries:
-                    if instrumented:
-                        n_retried.inc()
-                    retried.append(ent.index)
-                    time.sleep(backoff_s * (2 ** attempt))
-                    attempt += 1
-                    try:
-                        dispatch(ent.batch, ent.index, attempt)
-                        return
-                    except Exception as nxt:
-                        exc = nxt
-            if instrumented:
-                n_failed.inc()
-            if isolate:
-                failed.append(ent.index)
-                return
-            raise RuntimeError(
-                f"run_streaming: batch {ent.index} failed while draining"
-                f" (attempt {attempt + 1}): {exc}") from exc
-        if instrumented:
-            in_flight.dec()
         lat = time.perf_counter() - ent.t
-        if (watch is not None and ent.attempt < max_retries
-                and watch.late(ent.index, lat, deadline_s)):
-            if instrumented:
-                n_retried.inc()
-            retried.append(ent.index)
-            time.sleep(backoff_s * (2 ** ent.attempt))
-            dispatch(ent.batch, ent.index, ent.attempt + 1)
-            return
-        results[ent.index] = out
-        latencies.append(lat)
         if instrumented:
             lat_hist.record(lat)
+        outputs.append(out)
+        latencies.append(lat)
 
     t0 = time.perf_counter()
     try:
         for i, batch in enumerate(batches):
-            count = i + 1
             n = int(np.prod(np.shape(batch)))
             pixels += n
             if instrumented:
                 n_batches.inc()
                 n_pixels.inc(n)
-            dispatched = True
+            t = time.perf_counter()
             try:
-                dispatch(batch, i, 0)
+                with _obs.span("stream:dispatch", batch=i):
+                    fut = fn(batch)
             except Exception as exc:
-                dispatched = False
-                attempt = 0
-                if retry_failures:
-                    # Same bounded retry budget as the drain path: a
-                    # synchronously-raising dispatch may be transient
-                    # (device hiccup) just like an async drain failure.
-                    while attempt < max_retries:
-                        if instrumented:
-                            n_retried.inc()
-                        retried.append(i)
-                        time.sleep(backoff_s * (2 ** attempt))
-                        attempt += 1
-                        try:
-                            dispatch(batch, i, attempt)
-                            dispatched = True
-                            break
-                        except Exception as nxt:
-                            exc = nxt
-                if not dispatched:
-                    if not isolate:
-                        raise RuntimeError(
-                            f"run_streaming: batch {i} failed during "
-                            f"dispatch (attempt {attempt + 1}): {exc}"
-                        ) from exc
-                    if instrumented:
-                        n_failed.inc()
-                    failed.append(i)
-            if dispatched:
-                if degrade is not None:
-                    if degrade.observe(batch):
-                        # Tripped on THIS batch: settle the suspect
-                        # in-flight future and re-run the batch on the
-                        # recovered plan (which serves the rest of the
-                        # stream too).
-                        stale = pending.pop()
-                        _settle(stale.fut)
-                        if instrumented:
-                            in_flight.dec()
-                        active[0] = degrade.run
-                        dispatch(batch, i, stale.attempt)
-                    if degrade.level:
-                        degraded.append(i)
+                raise fail(i, "during dispatch", exc) from exc
+            if instrumented:
+                in_flight.inc()
+            pending.append(_InFlight(t, fut, i))
             while len(pending) >= depth:
                 drain()
         while pending:
@@ -484,14 +339,9 @@ def run_streaming(fn: Callable, batches: Iterable[np.ndarray], *,
             _settle(ent.fut)
             if instrumented:
                 in_flight.dec()
-    outputs: List[Optional[np.ndarray]] = [results.get(i)
-                                           for i in range(count)]
     return StreamResult(outputs=outputs,
                         seconds=time.perf_counter() - t0, pixels=pixels,
-                        batch_seconds=tuple(latencies),
-                        failed=tuple(failed),
-                        retried=tuple(dict.fromkeys(retried)),
-                        degraded=tuple(degraded))
+                        batch_seconds=tuple(latencies))
 
 
 def _psnr_cell(psnr_db: float) -> str:

@@ -9,9 +9,9 @@ error outside the config's exact band — the installed
 garbage, :class:`DegradePolicy` swaps the compiled plan for the
 NEXT-CHEAPEST config on the exact Pareto frontier that is strictly more
 accurate than the current one — ultimately the exact adder — re-budgets
-the monitor to the new config, and tells the streaming executor to
-re-run the batch that tripped.  Energy degrades one frontier rung at a
-time; quality recovers immediately.
+the monitor to the new config, and serves the batch that tripped on
+the new plan.  Energy degrades one frontier rung at a time; quality
+recovers immediately.
 
 Fallback plans compile WITHOUT the fault: degrading models swapping the
 defective approximate block for a different (healthy) operating point,
@@ -103,10 +103,10 @@ class DegradePolicy:
       ladder: override the fallback sequence (default
         :func:`pareto_ladder` of the pipe's spec).
 
-    Usage: pass as ``degrade=`` to
-    :func:`repro.imgproc.corpus.run_streaming`, or drive it manually —
-    ``observe(batch)`` returns True the moment a fallback swap happened
-    (the caller must then re-run the batch via :meth:`run`).  Requires
+    Usage: serve a stream through :meth:`stream`, or drive it manually
+    — ``observe(batch)`` returns True the moment a fallback swap
+    happened, and :meth:`run` then serves the batch on the recovered
+    plan.  Requires
     live telemetry (:func:`repro.obs.trace.enable`): drift capture is
     compiled out otherwise, and silently observing nothing would defeat
     the whole point, so :meth:`observe` refuses to run blind.
@@ -231,6 +231,20 @@ class DegradePolicy:
     def run(self, batch):
         """Execute the CURRENT plan (base, or fallback after a trip)."""
         return self.pipe(batch)
+
+    def stream(self, batches) -> Tuple[List[np.ndarray], Tuple[int, ...]]:
+        """Serve ``batches`` in order: observe each, then run it on the
+        current plan, so the batch that trips is served by the
+        recovered plan, as is every later one.  Returns the host
+        outputs and the indices served while degraded."""
+        outs: List[np.ndarray] = []
+        degraded: List[int] = []
+        for i, batch in enumerate(batches):
+            self.observe(batch)
+            outs.append(np.asarray(self.run(batch)))
+            if self.level:
+                degraded.append(i)
+        return outs, tuple(degraded)
 
     def __repr__(self) -> str:
         cur = self.pipe.engine.spec.short_name

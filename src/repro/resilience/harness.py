@@ -8,10 +8,10 @@ Two entry points, both returning trajectory-ready records (see
   rate x adder config over the corpus pipelines and scores each cell's
   PSNR/SSIM against the float golden — the "how much quality does this
   defect cost" curves committed to ``BENCH_faults.json``.
-- :func:`recovery_cell` runs the full self-healing loop — faulted plan,
-  :class:`~repro.resilience.degrade.DegradePolicy`, hardened
-  :func:`~repro.imgproc.corpus.run_streaming` — and reports the dB the
-  fallback ladder claws back versus serving the fault unmitigated.
+- :func:`recovery_cell` runs the full self-healing loop — faulted plan
+  served through :meth:`~repro.resilience.degrade.DegradePolicy.stream`
+  — and reports the dB the fallback ladder claws back versus serving
+  the fault unmitigated.
 
 Everything is seeded (synthetic batches, transient-flip hashes, the
 deterministic ladder), so a campaign replays bit-identically run to
@@ -327,19 +327,17 @@ def recovery_cell(workload: str = "pipe_blur_sharpen_down",
     record.
 
     A stream of seeded batches runs through a fault-injected plan twice:
-    unmitigated, and under the hardened streamer with a
-    :class:`DegradePolicy` watching (its drift monitor trips within the
-    first batch's sample budget, the tripping batch re-runs on the
-    recovered plan, and the rest of the stream serves from it).  The
-    headline metric is ``recovery_db`` — mean PSNR with the fallback
-    minus mean PSNR without.
+    unmitigated, and through :meth:`DegradePolicy.stream` (its drift
+    monitor trips within the first batch's sample budget, the tripping
+    batch is served by the recovered plan, and so is the rest of the
+    stream).  The headline metric is ``recovery_db`` — mean PSNR with
+    the fallback minus mean PSNR without.
 
     Telemetry is force-enabled for the duration (the policy's shadow
     capture needs it) and restored on exit, so the cell is callable from
     a cold benchmark process."""
     from repro.image.quality import psnr as _psnr
-    from repro.imgproc.corpus import _golden, run_streaming, \
-        synthetic_batch
+    from repro.imgproc.corpus import _golden, synthetic_batch
     from repro.imgproc.plan import compile_pipeline
     from repro.imgproc.workloads import get_workload
     from repro.resilience.degrade import DegradePolicy
@@ -365,13 +363,13 @@ def recovery_cell(workload: str = "pipe_blur_sharpen_down",
     try:
         policy = DegradePolicy(pipe, min_samples=min_samples)
         nofallback = [np.asarray(pipe(b)) for b in batches]
-        res = run_streaming(pipe, batches, depth=2, degrade=policy)
+        outs, degraded = policy.stream(batches)
     finally:
         if not was_enabled:
             _obs.disable()
 
     psnr_nofallback = _mean_psnr(nofallback)
-    psnr_fallback = _mean_psnr(res.outputs)
+    psnr_fallback = _mean_psnr(outs)
     return {
         "op": "fault_recovery",
         "workload": workload,
@@ -387,5 +385,5 @@ def recovery_cell(workload: str = "pipe_blur_sharpen_down",
         "recovery_db": psnr_fallback - psnr_nofallback,
         "degrade_level": policy.level,
         "trips": policy.trips,
-        "batches_degraded": len(res.degraded),
+        "batches_degraded": len(degraded),
     }

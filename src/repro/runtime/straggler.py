@@ -7,10 +7,10 @@ a callback hook for (b); (c) is runtime/elastic.py + checkpoint restore.
 
 :meth:`StragglerMonitor.late` is the single source of truth for "this
 work item is late" across the repo: the training loop's per-step flag
-and the streaming executor's per-batch deadline path
-(:func:`repro.imgproc.corpus.run_streaming`) both route through it —
-an explicit deadline when the caller has an SLO, the median/MAD outlier
-filter when it only has the stream's own history.
+and the serving scheduler's per-batch timeout
+(:class:`repro.serving.Scheduler`) both route through it — an explicit
+deadline when the caller has an SLO, the median/MAD outlier filter when
+it only has its own history.
 """
 
 from __future__ import annotations

@@ -99,10 +99,8 @@ class Scheduler:
       breaker: optional :class:`~repro.serving.breaker.CircuitBreaker`
         (attach a ``DegradePolicy`` to it for Pareto-rung fallback).
       straggler: optional :class:`~repro.runtime.straggler
-        .StragglerMonitor`; defaults to a deadline-only monitor, the
-        same construction :func:`repro.imgproc.corpus.run_streaming`
-        uses, so the one ``late`` definition judges serving timeouts
-        too.
+        .StragglerMonitor`; defaults to a deadline-only monitor, so
+        the one ``late`` definition judges serving timeouts too.
       integrity: optional integrity watchdog(s) — anything with the
         ``maybe_run(now)`` cadence protocol
         (:class:`~repro.integrity.scrub.LutScrubber`,

@@ -1,4 +1,4 @@
-"""repro.resilience: fault injection, hardened streaming, self-healing.
+"""repro.resilience: fault injection, stream teardown, self-healing.
 
 Acceptance (PR 8): a seeded stuck-at-1 campaign on the three-stage
 pipeline where (a) the faulted datapath is bit-identical across
@@ -7,9 +7,6 @@ budget, (c) the degradation ladder recovers >= 5 dB PSNR versus
 serving the fault unmitigated, and (d) a poisoned batch leaves zero
 leaked in-flight futures.  Long campaigns ride the ``slow`` marker.
 """
-
-import collections
-import time
 
 import numpy as np
 import pytest
@@ -264,13 +261,14 @@ def _poisoned_stream(n=6, bad=2):
     return fn, batches, futs
 
 
-def test_poisoned_batch_leaves_no_pending_futures(fresh_obs):
+@pytest.mark.parametrize("depth", [1, 3, 64])
+def test_poisoned_batch_leaves_no_pending_futures(fresh_obs, depth):
     """Satellite 1 + acceptance: a mid-stream raise re-raises with the
     failing batch index AND every dispatched future is settled (drained
     or dropped) before the exception escapes — zero leaks, gauge at 0."""
     fn, batches, futs = _poisoned_stream(n=6, bad=2)
     with pytest.raises(RuntimeError, match=r"batch 2"):
-        run_streaming(fn, batches, depth=3)
+        run_streaming(fn, batches, depth=depth)
     assert futs and all(f.settled for f in futs)
     snap = obs.metrics_snapshot()
     assert snap["gauges"]["stream.batches_in_flight"]["value"] == 0
@@ -288,162 +286,81 @@ def test_dispatch_failure_names_batch_index():
         run_streaming(fn, batches, depth=2)
 
 
-@pytest.mark.parametrize("depth", [1, 4])
-def test_isolate_records_failure_and_stream_survives(depth):
-    fn, batches, futs = _poisoned_stream(n=6, bad=2)
-    r = run_streaming(fn, batches, depth=depth, isolate=True)
-    assert r.failed == (2,)
-    assert r.outputs[2] is None
-    for i in (0, 1, 3, 4, 5):
-        np.testing.assert_array_equal(r.outputs[i], batches[i])
-    assert all(f.settled for f in futs)
-    assert len(r.batch_seconds) == 5  # only accepted batches time in
+@pytest.mark.parametrize("depth", [1, 2, 4, 64])
+def test_stream_keeps_at_most_depth_in_flight(depth):
+    """The pipelining contract: dispatches run ahead of the drain by at
+    most ``depth`` batches, and reach that many whenever the stream is
+    long enough."""
+    live = [0, 0]                     # undrained dispatches, high water
 
+    class Counted(_Fut):
+        drained = False
 
-def test_isolate_depth_invariance():
-    """depth=1 (blocking) and depth=4 (pipelined) agree on outputs AND
-    on which batches failed."""
-    runs = []
-    for depth in (1, 4):
-        fn, batches, _ = _poisoned_stream(n=6, bad=3)
-        runs.append(run_streaming(fn, batches, depth=depth, isolate=True))
-    a, b = runs
-    assert a.failed == b.failed == (3,)
-    assert len(a.outputs) == len(b.outputs)
-    for x, y in zip(a.outputs, b.outputs):
-        if x is None:
-            assert y is None
-        else:
-            np.testing.assert_array_equal(x, y)
-
-
-def test_deadline_retry_with_backoff():
-    """A batch that blows its deadline re-dispatches (bounded, with
-    backoff) and the stream still returns every output in order."""
-    calls = collections.Counter()
+        def __array__(self, dtype=None, copy=None):
+            if not self.drained:
+                self.drained = True
+                live[0] -= 1
+            return super().__array__(dtype, copy)
 
     def fn(batch):
-        i = int(batch[0, 0, 0])
-        calls[i] += 1
-        if i == 1 and calls[i] == 1:
-            time.sleep(0.05)
-        return batch
+        live[0] += 1
+        live[1] = max(live[1], live[0])
+        return Counted(batch)
 
-    # depth=1 so each batch's measured latency is its own fn time (at
-    # depth>1 a slow neighbor's dispatch counts into in-flight waiting
-    # and would legitimately flag other batches too).
-    _, batches, _ = _poisoned_stream(n=4)
-    r = run_streaming(fn, batches, depth=1, deadline_s=0.02,
-                      max_retries=2, backoff_s=0.0)
-    assert r.retried == (1,)
-    assert r.failed == ()
-    assert calls[1] == 2 and calls[0] == 1
-    for i in range(4):
-        np.testing.assert_array_equal(r.outputs[i], batches[i])
+    n = 10
+    _, batches, _ = _poisoned_stream(n=n)
+    r = run_streaming(fn, batches, depth=depth)
+    assert live == [0, min(depth, n)]
+    for got, want in zip(r.outputs, batches):
+        np.testing.assert_array_equal(got, want)
+
+
+def _stops_early(batches, stop):
+    for j, b in enumerate(batches):
+        if j == stop:
+            return
+        yield b
+
+
+@pytest.mark.parametrize("n,stop", [(2, None), (9, None), (8, 5)],
+                         ids=["fewer-than-depth", "more-than-depth",
+                              "generator-stops-early"])
+def test_stream_matches_sequential_for_any_length(n, stop):
+    """Outputs in input order, one per batch the iterable yields, equal
+    to the blocking loop's — however the stream's length compares with
+    ``depth``, and when a generator ends before its pool does (as the
+    benchmark's closed loop does when its window closes)."""
+    depth = 4
+    batches = [np.full((1, 8, 8), i, np.uint8) for i in range(n)]
+    fed = batches if stop is None else _stops_early(batches, stop)
+    r = run_streaming(lambda b: jnp.asarray(b) + 1, fed, depth=depth)
+    served = batches[:stop]
+    want = [np.asarray(jnp.asarray(b) + 1) for b in served]
+    assert len(r.outputs) == len(want) == len(r.batch_seconds)
+    for got, exp in zip(r.outputs, want):
+        assert isinstance(got, np.ndarray)
+        np.testing.assert_array_equal(got, exp)
+    assert r.pixels == sum(b.size for b in served)
 
 
 def test_empty_stream_is_well_formed():
     """Zero batches: a complete, zero-throughput StreamResult — no
-    division error, no nan, empty partitions."""
+    division error, no nan."""
     r = run_streaming(lambda b: b, [])
     assert r.outputs == []
     assert r.pixels == 0
     assert r.mpix_per_s == 0.0
-    assert r.failed == r.retried == r.degraded == ()
     assert r.batch_seconds == ()
     # Direct zero-seconds guard (instantaneous streams, old pickles).
     z = StreamResult(outputs=[], seconds=0.0, pixels=0)
     assert z.mpix_per_s == 0.0
 
 
-def test_retry_failures_recovers_transient_dispatch_fault():
-    """retry_failures=True: a dispatch that raises ONCE re-dispatches
-    with backoff and the stream completes clean (transient device
-    hiccup, PR-9 semantics)."""
-    calls = collections.Counter()
-
-    def fn(batch):
-        i = int(batch[0, 0, 0])
-        calls[i] += 1
-        if i == 1 and calls[i] == 1:
-            raise RuntimeError("transient dispatch fault")
-        return batch
-
-    _, batches, _ = _poisoned_stream(n=4)
-    r = run_streaming(fn, batches, depth=2, retry_failures=True,
-                      max_retries=2, backoff_s=0.0)
-    assert r.retried == (1,)
-    assert r.failed == ()
-    assert calls[1] == 2
-    for i in range(4):
-        np.testing.assert_array_equal(r.outputs[i], batches[i])
-
-
-def test_retry_failures_recovers_transient_drain_fault():
-    """Same recovery for the async path: the FIRST future for a batch
-    poisons its drain, the re-dispatched one is healthy."""
-    seen = collections.Counter()
-
-    def fn(batch):
-        i = int(batch[0, 0, 0])
-        seen[i] += 1
-        return _Fut(batch, raise_on_drain=(i == 2 and seen[i] == 1))
-
-    _, batches, _ = _poisoned_stream(n=5)
-    r = run_streaming(fn, batches, depth=2, retry_failures=True,
-                      max_retries=2, backoff_s=0.0)
-    assert r.retried == (2,)
-    assert r.failed == ()
-    assert seen[2] == 2
-    for i in range(5):
-        np.testing.assert_array_equal(r.outputs[i], batches[i])
-
-
-@pytest.mark.parametrize("depth", [1, 3])
-def test_retry_exhaustion_lands_in_failed(depth):
-    """Satellite acceptance: a batch that fails EVERY retry surfaces in
-    ``StreamResult.failed`` with its index (isolate) after consuming
-    its full attempt budget."""
-    attempts = collections.Counter()
-
-    def fn(batch):
-        i = int(batch[0, 0, 0])
-        attempts[i] += 1
-        return _Fut(batch, raise_on_drain=(i == 2))
-
-    _, batches, _ = _poisoned_stream(n=5)
-    r = run_streaming(fn, batches, depth=depth, retry_failures=True,
-                      isolate=True, max_retries=2, backoff_s=0.0)
-    assert r.failed == (2,)
-    assert r.outputs[2] is None
-    assert 2 in r.retried
-    assert attempts[2] == 3           # first try + max_retries
-    for i in (0, 1, 3, 4):
-        np.testing.assert_array_equal(r.outputs[i], batches[i])
-
-
-def test_retry_exhaustion_without_isolate_raises_with_attempts():
-    def fn(batch):
-        if int(batch[0, 0, 0]) == 1:
-            raise RuntimeError("hard fault")
-        return batch
-
-    _, batches, _ = _poisoned_stream(n=3)
-    with pytest.raises(RuntimeError, match=r"batch 1 .*attempt 3"):
-        run_streaming(fn, batches, depth=2, retry_failures=True,
-                      max_retries=2, backoff_s=0.0)
-
-
-def test_run_streaming_rejects_bad_knobs():
+@pytest.mark.parametrize("depth", [0, -1])
+def test_run_streaming_rejects_bad_depth(depth):
     batches = [np.zeros((1, 4, 4), np.uint8)]
     with pytest.raises(ValueError, match="depth"):
-        run_streaming(lambda b: b, batches, depth=0)
-    with pytest.raises(ValueError, match="deadline_s"):
-        run_streaming(lambda b: b, batches, deadline_s=0.0)
-    with pytest.raises(ValueError, match="max_retries"):
-        run_streaming(lambda b: b, batches, max_retries=-1)
-    with pytest.raises(ValueError, match="backoff_s"):
-        run_streaming(lambda b: b, batches, backoff_s=-0.1)
+        run_streaming(lambda b: b, batches, depth=depth)
 
 
 def test_straggler_late_is_single_source_of_truth():
@@ -522,13 +439,13 @@ def test_run_streaming_degrade_hook(fresh_obs):
                             fault=fault)
     pol = DegradePolicy(pipe, min_samples=512)
     batches = [synthetic_batch(2, 32, seed=9 + i) for i in range(3)]
-    r = run_streaming(pipe, batches, depth=2, degrade=pol)
+    outs, degraded = pol.stream(batches)
     assert pol.level >= 1
-    assert r.degraded and r.degraded[0] == 0  # tripping batch re-ran
-    assert r.failed == ()
+    assert degraded and degraded[0] == 0  # tripping batch re-ran
+    assert len(outs) == len(batches)
     # Every degraded output came from the recovered plan.
-    for i in r.degraded:
-        np.testing.assert_array_equal(np.asarray(r.outputs[i]),
+    for i in degraded:
+        np.testing.assert_array_equal(outs[i],
                                       np.asarray(pol.pipe(batches[i])))
 
 
